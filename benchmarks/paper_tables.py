@@ -487,9 +487,9 @@ def kernels(rows: int = 256):
 
     On a CPU container the compiled ``pallas`` backend is unavailable
     (recorded as such) and ``pallas-interpret`` is *slower* than ``ref`` —
-    the interpreter exists for parity/CI, not speed; the speedup column is
-    meaningful on accelerator hosts where ``pallas`` compiles.  All timings
-    are second-call (warm jit caches)."""
+    the interpreter exists for parity/CI, not speed.  On an accelerator a
+    failed ``pallas`` probe raises.  All timings are second-call (warm jit
+    caches) host-clock times."""
     import dataclasses
     import json
 
@@ -504,6 +504,11 @@ def kernels(rows: int = 256):
         status[name] = "ok" if ok else reason
         if ok:
             usable.append(name)
+        elif name == "pallas" and jax.default_backend() != "cpu":
+            # on an accelerator the compiled kernels are the product: a
+            # failed probe is a fault, never a dropped column
+            raise backend.BackendUnavailableError(
+                f"pallas unusable on {jax.default_backend()}: {reason}")
         yield (f"kernels/backend/{name}", 0.0, status[name][:60])
 
     rng = np.random.default_rng(0)

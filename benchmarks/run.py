@@ -9,6 +9,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset (e.g. table1,fig6a)")
     args = ap.parse_args()
+    from repro.core.backend import enable_compile_cache
+    enable_compile_cache()
     from . import paper_tables
     subset = args.only.split(",") if args.only else list(paper_tables.ALL)
     print("name,us_per_call,derived")

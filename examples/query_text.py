@@ -61,4 +61,6 @@ def main(n_knows=150, n_persons=32, cfg=CFG, seed=13):
 
 
 if __name__ == "__main__":
+    from repro.core.backend import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -73,4 +73,6 @@ def main(n_knows=200, n_persons=32, cfg=CFG, seed=7):
 
 
 if __name__ == "__main__":
+    from repro.core.backend import enable_compile_cache
+    enable_compile_cache()
     main()

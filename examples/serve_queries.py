@@ -44,6 +44,10 @@ the dead owner **degrades the query, not the service**: the federated
 IC2 fails typed (``OwnerUnavailableError``) while IC13 — cut entirely to
 owner-a — still proves and verifies.
 
+A chip belongs to one process, so only the owner processes may use an
+accelerator; the driver and the verifiers pin JAX to the CPU (verifying
+is host-side work).
+
 The driver checks all of it, phase by phase: recovery happened, every
 bundle verified in both verifier processes, heads advanced exactly once,
 equivocation was detected by both peers, and the federated act held.  A
@@ -524,6 +528,8 @@ def run_verifier(args) -> None:
 def _spawn(role: str, d: str, args, extra=()) -> subprocess.Popen:
     env = dict(os.environ)
     env["PYTHONPATH"] = str(SRC) + os.pathsep + env.get("PYTHONPATH", "")
+    if role != "owner":
+        env["JAX_PLATFORMS"] = "cpu"     # the chip is the owner's
     cmd = [sys.executable, str(Path(__file__).resolve()), "--role", role,
            "--dir", d, "--queries", str(args.queries),
            *(() if args.faults else ("--no-faults",)),
@@ -756,8 +762,14 @@ def main(argv=None, n_knows=128, n_persons=24, cfg=CFG):
         return run_owner(args)
     if args.role == "verifier":
         return run_verifier(args)
+    # a chip belongs to one process: the owner proves on it; the driver's
+    # own JAX work (commit, forged head, federation act) runs on the host
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     return run_driver(args)
 
 
 if __name__ == "__main__":
+    from repro.core.backend import enable_compile_cache
+    enable_compile_cache()
     main()

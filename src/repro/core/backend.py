@@ -18,9 +18,9 @@ Backends
     the exact kernel code paths, so kernel drift against the reference is
     caught on every PR without accelerator hardware.
 ``pallas``
-    The same kernels compiled for a real accelerator (``interpret=False``).
-    Raises at dispatch time on hosts whose jax backend cannot lower Pallas
-    (plain CPU); gate on :func:`probe` before selecting it.
+    The same kernels compiled for the TPU (``interpret=False``).  Raises at
+    dispatch time on a CPU-only host; :func:`require` checks it runs, and
+    gives its permutation bit for bit, before a run relies on it.
 
 Selection
 ---------
@@ -162,24 +162,60 @@ def use(name: str = None):
         scopes.pop()
 
 
-def probe(name: str) -> tuple:
-    """(usable, reason) — run a tiny permutation under ``name``.
+class BackendUnavailableError(RuntimeError):
+    """A backend that was asked for explicitly cannot run on this host."""
 
-    The compiled ``pallas`` backend needs an accelerator-capable jax
-    backend; on plain CPU it raises at lowering time, which this converts
-    into a clean availability answer for benchmarks and launch scripts."""
+
+def probe(name: str) -> tuple:
+    """(usable, reason) — run a small permutation under ``name`` and compare
+    it with the reference bit for bit.
+
+    The compiled ``pallas`` backend needs a TPU; on a CPU host it raises at
+    lowering time, which this turns into an availability answer."""
     import numpy as np
     try:
         be = get(name)
+        states = np.arange(2 * 16, dtype=np.uint32).reshape(2, 16) * 7919
         with use(name):
-            out = be.permute(np.zeros((2, 16), np.uint32))
-        if out.shape != (2, 16):
-            return False, f"probe returned shape {out.shape}"
+            out = np.asarray(be.permute(states))
+        from . import hashing
+        want = np.asarray(hashing.permute_ref(states))
+        if not np.array_equal(out, want):
+            return False, "permutation differs from the reference"
         return True, "ok"
     except UnknownBackendError:
         raise
     except Exception as e:  # noqa: BLE001 — lowering errors vary by platform
         return False, f"{type(e).__name__}: {e}"
+
+
+def require(name: str) -> ComputeBackend:
+    """The backend ``name``, after :func:`probe` passed; raises
+    :class:`BackendUnavailableError` with the probe's reason otherwise."""
+    ok, reason = probe(name)
+    if not ok:
+        raise BackendUnavailableError(f"backend {name!r} unusable: {reason}")
+    return get(name)
+
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+CACHE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         os.pardir, os.pardir, os.pardir, ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn JAX's persistent compilation cache on; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads the
+    directory from it and nothing else is set.  Otherwise the cache lives
+    at one fixed path inside the checkout (``<repo>/.jax_cache``), so every
+    run of this checkout finds what an earlier run compiled.  Entry points
+    call this before their first JAX computation; tests do not."""
+    import jax
+    if not os.environ.get(CACHE_ENV):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.normpath(CACHE_DIR))
+    return jax.config.jax_compilation_cache_dir
 
 
 # ---------------------------------------------------------------------------
@@ -203,22 +239,28 @@ def _ref_grand_product_ext(x):
 
 def _pallas_permute(interpret: bool):
     def permute(states):
+        from ..kernels import on_mesh
         from ..kernels.poseidon import ops
-        return ops.permute(states, interpret=interpret)
+        return on_mesh(lambda s: ops.permute(s, interpret=interpret), states,
+                       split=True)
     return permute
 
 
 def _pallas_ntt(interpret: bool):
     def ntt(x, inverse: bool = False):
+        from ..kernels import on_mesh
         from ..kernels.ntt import ops
-        return ops.ntt(x, inverse=inverse, interpret=interpret)
+        return on_mesh(lambda v: ops.ntt(v, inverse=inverse,
+                                         interpret=interpret), x, split=True)
     return ntt
 
 
 def _pallas_grand_product_ext(interpret: bool):
     def grand_product_ext(x):
+        from ..kernels import on_mesh
         from ..kernels.grand_product import ops
-        return ops.grand_product_ext(x, interpret=interpret)
+        return on_mesh(lambda v: ops.grand_product_ext(v, interpret=interpret),
+                       x, split=False)
     return grand_product_ext
 
 
@@ -242,7 +284,7 @@ register(ComputeBackend(
 
 register(ComputeBackend(
     name="pallas",
-    description="compiled Pallas kernels; needs an accelerator jax backend",
+    description="compiled Pallas kernels; needs a TPU",
     permute=_pallas_permute(False),
     ntt=_pallas_ntt(False),
     grand_product_ext=_pallas_grand_product_ext(False),

@@ -9,7 +9,10 @@ Conventions
 * Fp elements: ``jnp.uint32`` arrays, canonical representatives in [0, p).
 * Fp4 elements: uint32 arrays whose **last axis has size 4** (coefficients of
   1, x, x^2, x^3 in Fp[x]/(x^4 - W)).
-* All ops are vectorized and jit-safe.
+* All ops are vectorized and jit-safe.  The elementwise ones are jitted
+  themselves: the prover calls them eagerly, and one compiled program per
+  shape costs far less to compile and dispatch — on the TPU above all —
+  than the handful of separate ops each would otherwise be.
 """
 from __future__ import annotations
 
@@ -60,63 +63,129 @@ def fp(x) -> jnp.ndarray:
     return arr
 
 
+@jax.jit
 def fadd(a, b):
     s = a.astype(_U32) + b.astype(_U32)          # < 2^32, no overflow (a,b < 2^31)
     return jnp.where(s >= P, s - P, s)
 
 
+@jax.jit
 def fsub(a, b):
     a = a.astype(_U32)
     b = b.astype(_U32)
     return jnp.where(a >= b, a - b, a + (_U32(P) - b))
 
 
+@jax.jit
 def fneg(a):
     a = a.astype(_U32)
     return jnp.where(a == 0, a, _U32(P) - a)
 
 
+_M32 = 0xFFFFFFFF
+_BARRETT = (1 << 93) // P          # < 2^63
+
+
+@jax.jit
+def mod_p(x):
+    """``x mod P`` for uint64 ``x``, as uint64, without a 64-bit division.
+
+    XLA emulates 64-bit integers on the TPU, and its 64-bit remainder
+    compiles to a graph so large that a function of a few dozen field
+    products takes minutes to compile.  Barrett reduction needs only 64-bit
+    multiplies, shifts and adds: with ``m = floor(2^93 / P)``,
+    ``floor(x * m / 2^93)`` is ``floor(x / P)`` or one less for every
+    ``x < 2^64``, so one conditional subtraction gives the remainder."""
+    x = jnp.asarray(x).astype(_U64)
+    x0, x1 = x & _U64(_M32), x >> _U64(32)
+    m0, m1 = _U64(_BARRETT & _M32), _U64(_BARRETT >> 32)
+    lo, mid0, mid1 = x0 * m0, x1 * m0, x0 * m1
+    carry = ((lo >> _U64(32)) + (mid0 & _U64(_M32)) +
+             (mid1 & _U64(_M32))) >> _U64(32)
+    hi = x1 * m1 + (mid0 >> _U64(32)) + (mid1 >> _U64(32)) + carry
+    r = x - (hi >> _U64(29)) * _U64(P)          # hi = floor(x * m / 2^64)
+    return jnp.where(r >= _U64(P), r - _U64(P), r)
+
+
+@jax.jit
 def fmul(a, b):
     prod = a.astype(_U64) * b.astype(_U64)
-    return (prod % _U64(P)).astype(_U32)
+    return mod_p(prod).astype(_U32)
+
+
+def _square_and_multiply(mul, one, base, e: int):
+    """base ** e for a static python-int exponent, as a ``fori_loop`` over
+    its bits: one squaring and one multiply are traced, not ~60 (compile
+    time on the TPU grows with the number of traced products)."""
+    if e == 0:
+        return one
+    bits = jnp.asarray([(e >> i) & 1 for i in range(e.bit_length())],
+                       jnp.uint32)
+
+    def step(i, acc):
+        result, base = acc
+        result = jnp.where(bits[i] == 1, mul(result, base), result)
+        return result, mul(base, base)
+
+    result, _ = jax.lax.fori_loop(0, e.bit_length(), step, (one, base))
+    return result
 
 
 @functools.partial(jax.jit, static_argnums=1)
 def fpow(a, e: int):
     """a ** e with a *static* python-int exponent (square and multiply)."""
-    result = jnp.full(jnp.shape(a), 1, _U32)
     base = jnp.asarray(a, _U32)
-    while e > 0:
-        if e & 1:
-            result = fmul(result, base)
-        base = fmul(base, base)
-        e >>= 1
-    return result
+    return _square_and_multiply(fmul, jnp.ones_like(base), base, e)
 
 
 def finv(a):
     return fpow(a, P - 2)
 
 
+_INV_BLOCK = 64
+
+
+def _montgomery_rows(mul, inv, one, x):
+    """Inverse of every element of ``x`` (shape ``(rows, ...)``, no zeros):
+    Montgomery's trick run down the rows, vectorised across the rest.
+
+    One forward scan of prefix products, one inversion of the row totals,
+    one backward scan: three products per element plus ``inv`` on one row.
+    Each scan body is traced once, so the compiled program does not grow
+    with the length (an ``associative_scan`` adds a level of products per
+    doubling, and XLA compiles every level)."""
+    total, excl = jax.lax.scan(lambda acc, xi: (mul(acc, xi), acc), one, x)
+
+    def back(acc, xs):          # acc: inverse of the product up to row i
+        xi, pre = xs
+        return mul(acc, xi), mul(acc, pre)
+
+    _, out = jax.lax.scan(back, inv(total), (x, excl), reverse=True)
+    return out
+
+
+def _blocked_inv(mul, inv, one, flat):
+    """Inverses of the rows of ``flat`` (``(n, ...)``, no zeros), folded
+    into at most ``_INV_BLOCK`` scan rows."""
+    n = flat.shape[0]
+    rows = min(_INV_BLOCK, n)
+    cols = -(-n // rows)
+    pad = jnp.broadcast_to(one, (rows * cols - n,) + flat.shape[1:])
+    x = jnp.concatenate([flat, pad]).reshape((rows, cols) + flat.shape[1:])
+    one_row = jnp.broadcast_to(one, (cols,) + flat.shape[1:])
+    out = _montgomery_rows(mul, inv, one_row, x)
+    return out.reshape((rows * cols,) + flat.shape[1:])[:n]
+
+
 @jax.jit
 def fbatch_inv(a):
-    """Montgomery batch inversion along the last axis: one finv total.
-
-    Zero entries map to zero (callers guard their own semantics).
-    """
-    safe = jnp.where(a == 0, _U32(1), a)
-    # inv(a_i) = (prefix-excl-self * suffix-excl-self) * inv(prod of all)
-    pref = jax.lax.associative_scan(fmul, safe, axis=-1)
-    total_inv = finv(pref[..., -1])
-    shifted = jnp.concatenate(
-        [jnp.ones_like(pref[..., :1]), pref[..., :-1]], axis=-1
-    )  # prefix product excluding self
-    # suffix products: reverse-scan
-    rev = jnp.flip(safe, axis=-1)
-    suf = jax.lax.associative_scan(fmul, rev, axis=-1)
-    suf = jnp.flip(suf, axis=-1)
-    suf_excl = jnp.concatenate([suf[..., 1:], jnp.ones_like(suf[..., :1])], axis=-1)
-    inv = fmul(fmul(shifted, suf_excl), total_inv[..., None])
+    """Inverse of every element (Montgomery batch inversion, blocked);
+    zero entries map to zero (callers guard their own semantics)."""
+    a = jnp.asarray(a, _U32)
+    if a.size == 0:
+        return a
+    safe = jnp.where(a == 0, _U32(1), a).reshape(-1)
+    inv = _blocked_inv(fmul, finv, _U32(1), safe).reshape(a.shape)
     return jnp.where(a == 0, _U32(0), inv)
 
 
@@ -167,6 +236,7 @@ def emul(a, b):
     return jnp.stack([c0, c1, c2, c3], axis=-1)
 
 
+@jax.jit
 def emul_fp(a_ext, b_fp):
     """Fp4 * Fp (scalar multiply each coefficient)."""
     return fmul(a_ext, b_fp[..., None].astype(_U32))
@@ -174,23 +244,17 @@ def emul_fp(a_ext, b_fp):
 
 @functools.partial(jax.jit, static_argnums=1)
 def epow(a, e: int):
-    result = jnp.broadcast_to(jnp.asarray(EXT_ONE), jnp.shape(a)).astype(_U32)
-    base = a
-    while e > 0:
-        if e & 1:
-            result = emul(result, base)
-        base = emul(base, base)
-        e >>= 1
-    return result
+    base = jnp.asarray(a, _U32)
+    one = jnp.broadcast_to(jnp.asarray(EXT_ONE), base.shape).astype(_U32)
+    return _square_and_multiply(emul, one, base, e)
 
 
-@jax.jit
-def einv(a):
-    """Inverse in Fp4 via the norm map (two Frobenius conjugates).
+def _norm_parts(a):
+    """``(c, n)`` with ``a * c = n`` in Fp: ``c`` is the product of the
+    three Frobenius conjugates of ``a``, ``n = N(a)`` its norm.
 
     For q = p, Frobenius phi(a)(x) = a(x^p). Since x^4 = W, x^p = x * W^((p-1)/4)
     with (p-1) divisible by 4. N(a) = a * phi(a) * phi^2(a) * phi^3(a) in Fp.
-    inv(a) = phi(a)*phi^2(a)*phi^3(a) / N(a).
     """
     s = _pow_py(W_EXT, (P - 1) // 4)  # x^p = s * x, s^4 = W^(p-1) = 1
     # phi^k multiplies coefficient i by s^(i*k)
@@ -198,30 +262,25 @@ def einv(a):
         mults = np.array([_pow_py(s, i * k) for i in range(4)], np.uint32)
         return fmul(v, jnp.asarray(mults))
 
-    a1 = frob(a, 1)
-    a2 = frob(a, 2)
-    a3 = frob(a, 3)
-    prod = emul(emul(a1, a2), a3)
+    prod = emul(emul(frob(a, 1), frob(a, 2)), frob(a, 3))
     norm = emul(a, prod)  # lies in Fp: coefficients 1..3 are ~0
-    n0 = norm[..., 0]
-    inv_n = finv(n0)
-    return emul_fp(prod, inv_n)
+    return prod, norm[..., 0]
 
 
-@jax.jit
+def einv(a):
+    """Inverse in Fp4 via the norm map: inv(a) = phi(a)phi^2(a)phi^3(a) / N(a)."""
+    prod, norm = _norm_parts(a)
+    return emul_fp(prod, finv(norm))
+
+
 def ebatch_inv(a):
-    """Batch inversion of Fp4 elements along axis -2 (stack of ext elements)."""
-    # fold to one inv via prefix/suffix products (like fbatch_inv but emul)
+    """Inverse of every Fp4 element via the norm map, with the norms
+    inverted together by :func:`fbatch_inv`; zero elements map to zero."""
+    a = jnp.asarray(a, _U32)
     is_zero = jnp.all(a == 0, axis=-1, keepdims=True)
     one = jnp.broadcast_to(jnp.asarray(EXT_ONE), a.shape).astype(_U32)
-    safe = jnp.where(is_zero, one, a)
-    pref = jax.lax.associative_scan(emul, safe, axis=-2)
-    total_inv = einv(pref[..., -1, :])
-    shifted = jnp.concatenate([one[..., :1, :], pref[..., :-1, :]], axis=-2)
-    rev = jnp.flip(safe, axis=-2)
-    suf = jnp.flip(jax.lax.associative_scan(emul, rev, axis=-2), axis=-2)
-    suf_excl = jnp.concatenate([suf[..., 1:, :], one[..., :1, :]], axis=-2)
-    inv = emul(emul(shifted, suf_excl), total_inv[..., None, :])
+    prod, norm = _norm_parts(jnp.where(is_zero, one, a))
+    inv = emul_fp(prod, fbatch_inv(norm))
     return jnp.where(is_zero, jnp.zeros_like(inv), inv)
 
 
@@ -234,7 +293,7 @@ def rand_fp(key, shape):
     bits = jax.random.bits(key, shape, dtype=jnp.uint32).astype(_U64)
     bits2 = jax.random.bits(jax.random.fold_in(key, 1), shape, dtype=jnp.uint32)
     wide = (bits << _U64(32)) | bits2.astype(_U64)
-    return (wide % _U64(P)).astype(_U32)
+    return mod_p(wide).astype(_U32)
 
 
 def rand_ext(key, shape=()):

@@ -232,7 +232,7 @@ def fri_verify(proof: FriProof, tx: Transcript, cfg: FriConfig, n: int):
         np.vectorize(lambda e: pow(w_inv, int(e), F.P))(ij).astype(np.uint32))
     # c_j = n^{-1} s^{-j} sum_i v_i w^{-ij}
     prod = F.fmul(final[:, None, :], Wm[:, :, None])     # (i, j, 4)
-    sums = jnp.sum(prod.astype(jnp.uint64), axis=0) % jnp.uint64(F.P)
+    sums = F.mod_p(jnp.sum(prod.astype(jnp.uint64), axis=0))
     sj = np.array([pow(s_inv, j, F.P) * n_inv % F.P for j in range(size)], np.uint32)
     coeffs = F.fmul(sums.astype(_U32), jnp.asarray(sj)[:, None])
     ok &= bool(jnp.all(coeffs[deg_bound:] == 0))

@@ -61,8 +61,8 @@ def _matmul_mod(state, mat):
     """(batch..., 16) x (16, 16) modular matmul.  Sum of 16 products of
     values < 2^31: fits in uint64 (16 * 2^62 overflows — reduce per-term)."""
     prod = state[..., :, None].astype(_U64) * mat[None, :, :].astype(_U64)
-    prod = prod % _U64(F.P)                      # (batch..., 16, 16) < 2^31
-    s = jnp.sum(prod, axis=-2) % _U64(F.P)       # 16 * 2^31 < 2^36: safe
+    prod = F.mod_p(prod)                         # (batch..., 16, 16) < 2^31
+    s = F.mod_p(jnp.sum(prod, axis=-2))          # 16 * 2^31 < 2^36: safe
     return s.astype(_U32)
 
 
@@ -77,28 +77,26 @@ def permute(state: jnp.ndarray) -> jnp.ndarray:
 @jax.jit
 def permute_ref(state: jnp.ndarray) -> jnp.ndarray:
     """The pure-jnp reference permutation (the ``ref`` backend, and the
-    oracle the Pallas kernel is validated against)."""
+    oracle the Pallas kernel is validated against).  The rounds run as
+    ``fori_loop``s over the round-constant table, so each round kind is
+    traced and compiled once."""
     mds, rc = _params()
     mds = jnp.asarray(mds)
     rc = jnp.asarray(rc)
-    half = FULL_ROUNDS // 2
-    r = 0
-    for _ in range(half):
-        state = F.fadd(state, rc[r])
-        state = _sbox(state)
-        state = _matmul_mod(state, mds)
-        r += 1
-    for _ in range(PARTIAL_ROUNDS):
+
+    def full_round(r, state):
+        return _matmul_mod(_sbox(F.fadd(state, rc[r])), mds)
+
+    def partial_round(r, state):
         state = F.fadd(state, rc[r])
         state = state.at[..., 0].set(_sbox(state[..., 0]))
-        state = _matmul_mod(state, mds)
-        r += 1
-    for _ in range(half):
-        state = F.fadd(state, rc[r])
-        state = _sbox(state)
-        state = _matmul_mod(state, mds)
-        r += 1
-    return state
+        return _matmul_mod(state, mds)
+
+    half = FULL_ROUNDS // 2
+    mid = half + PARTIAL_ROUNDS
+    state = jax.lax.fori_loop(0, half, full_round, state.astype(_U32))
+    state = jax.lax.fori_loop(half, mid, partial_round, state)
+    return jax.lax.fori_loop(mid, mid + half, full_round, state)
 
 
 def compress(left: jnp.ndarray, right: jnp.ndarray) -> jnp.ndarray:

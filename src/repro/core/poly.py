@@ -143,7 +143,7 @@ def eval_at_ext(coeffs: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
     # sum_i c_i * zpows[i]: (..., n, 1) * (n, 4) -> mod-P dot
     prod = F.fmul(coeffs[..., None].astype(_U32), zpows)      # (..., n, 4)
     # modular sum along axis -2 (values < P; sum in uint64 then reduce)
-    s = jnp.sum(prod.astype(jnp.uint64), axis=-2) % jnp.uint64(F.P)
+    s = F.mod_p(jnp.sum(prod.astype(jnp.uint64), axis=-2))
     return s.astype(_U32)
 
 

@@ -111,7 +111,7 @@ def _lde_from_coeffs(coeffs: jnp.ndarray, blowup: int, shift: int) -> jnp.ndarra
 
 
 def _cumsum_mod(x: jnp.ndarray, axis=0) -> jnp.ndarray:
-    return (jnp.cumsum(x.astype(_U64), axis=axis) % _U64(F.P)).astype(_U32)
+    return F.mod_p(jnp.cumsum(x.astype(_U64), axis=axis)).astype(_U32)
 
 
 def opening_schedule(circuit: Circuit, blowup: int):
@@ -453,8 +453,9 @@ def _prove_impl(keys: Keys, advice_np: np.ndarray, instance_np: np.ndarray,
             idxs = [i for (k, i, rr) in sched if k == kind and rr == rot]
             if not idxs:
                 continue
-            coeffs = coeff_src[kind][jnp.asarray(idxs)]
-            vals = poly.eval_at_ext(coeffs, zr)
+            # repeat a row up to a multiple of 8: one gather/eval shape
+            rows = idxs + idxs[:1] * ((-len(idxs)) % 8)
+            vals = poly.eval_at_ext(coeff_src[kind][jnp.asarray(rows)], zr)
             for i, v in zip(idxs, np.asarray(vals)):
                 openings[(kind, i, rot)] = v
     for key in sched:

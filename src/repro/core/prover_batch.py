@@ -191,7 +191,7 @@ def _eval_at_ext_lanes(coeffs: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
     _, zpows = jax.lax.scan(step, one, None, length=n)     # (n, L, 4)
     zpows = jnp.moveaxis(zpows, 0, 1)                      # (L, n, 4)
     prod = F.fmul(coeffs[..., None].astype(_U32), zpows[:, None, :, :])
-    s = jnp.sum(prod.astype(jnp.uint64), axis=-2) % jnp.uint64(F.P)
+    s = F.mod_p(jnp.sum(prod.astype(jnp.uint64), axis=-2))
     return s.astype(_U32)
 
 
@@ -358,7 +358,9 @@ def _prove_batch_impl(keys: pv.Keys, witnesses: list, label: str,
             idxs = [i for (k, i, rr) in sched if k == kind and rr == rot]
             if not idxs:
                 continue
-            coeffs = coeff_src[kind][:, jnp.asarray(idxs)]
+            # repeat a row up to a multiple of 8: one gather/eval shape
+            rows = idxs + idxs[:1] * ((-len(idxs)) % 8)
+            coeffs = coeff_src[kind][:, jnp.asarray(rows)]
             vals = np.asarray(_eval_at_ext_lanes(coeffs, zr))  # (L, m, 4)
             for j, i in enumerate(idxs):
                 openings[(kind, i, rot)] = vals[:, j]

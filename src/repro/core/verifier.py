@@ -63,7 +63,9 @@ def verify(keys: Keys, instance_np: np.ndarray, proof: Proof,
             idxs = [i for (k, i, rr) in sched if k == kind and rr == rot]
             if not idxs:
                 continue
-            vals = poly.eval_at_ext(coeffs[jnp.asarray(idxs)], zr)
+            # repeat a row up to a multiple of 8: one gather/eval shape
+            rows = idxs + idxs[:1] * ((-len(idxs)) % 8)
+            vals = poly.eval_at_ext(coeffs[jnp.asarray(rows)], zr)
             for i, v in zip(idxs, np.asarray(vals)):
                 openings[(kind, i, rot)] = v
     # transcript absorbs ALL openings in schedule order (must match prover)

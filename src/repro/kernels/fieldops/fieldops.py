@@ -11,11 +11,7 @@ All kernels are validated in interpret mode against the uint64 oracle
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from ...core.field import P
 
@@ -143,16 +139,3 @@ def _mulmod_kernel(a_ref, b_ref, o_ref):
 def _fma_kernel(a_ref, b_ref, c_ref, o_ref):
     o_ref[...] = addmod(mulmod_limb(a_ref[...], b_ref[...]), c_ref[...])
 
-
-def _blocked_call(kernel, n_in, x_shape, block):
-    rows = x_shape[0] // block
-    return pl.pallas_call(
-        kernel,
-        grid=(rows,),
-        in_specs=[pl.BlockSpec((block,) + x_shape[1:], lambda i: (i,) + (0,) *
-                               (len(x_shape) - 1))] * n_in,
-        out_specs=pl.BlockSpec((block,) + x_shape[1:], lambda i: (i,) + (0,) *
-                               (len(x_shape) - 1)),
-        out_shape=jax.ShapeDtypeStruct(x_shape, _U32),
-        interpret=True,  # CPU container: interpret; TPU: set False
-    )

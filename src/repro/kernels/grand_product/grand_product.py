@@ -1,102 +1,46 @@
-"""Pallas kernels for the paper's Eq. (2) running-product accumulator.
+"""Pallas kernel for the paper's Eq. (2) running-product accumulator.
 
-Two-phase blocked scan (classic Blelloch decomposition adapted to a
-multiplicative monoid over BabyBear):
-  phase 1: each grid step loads a block into VMEM, computes the in-block
-           exclusive prefix products and the block total;
-  host    : tiny exclusive scan over the per-block totals (length n/block);
-  phase 2: each block's prefixes are scaled by its block offset.
-The modular multiply is the shared 16-bit-limb primitive (fieldops).
+One sequential pass over the grid.  Elements are laid out as ``k`` planes
+of ``(rows, 128)`` — ``k = 1`` for base-field scalars, ``k = 4`` for the
+coefficients of Fp4 elements — with position ``p`` at row ``p // 128``,
+lane ``p % 128``.  Each grid step loads a ``(k, block_rows, 128)`` block
+and computes, in VMEM:
 
-Two element types share the schedule: base-field scalars
-(:func:`grand_product`) and the quartic extension Fp4
-(:func:`grand_product_ext`) — the latter is what the prover's phase-2
-ext-column construction actually accumulates (running products of
-challenge-compressed tuples live in Fp4).  The in-kernel Fp4 multiply
-(:func:`_emul_limb`) is the same schoolbook x^4 = W_EXT reduction as
-``field.emul``, built from the 16-bit-limb primitives; modular arithmetic
-is exact, so both produce bit-identical field elements.
+1. the inclusive product along each row (log-step doubling with lane
+   rolls), with the carry — the product of all earlier blocks, kept in a
+   VMEM scratch across steps — folded into the first element,
+2. the inclusive product of the row totals down the block (sublane rolls),
+3. each row scaled by the rows before it, then moved one place on to
+   make the product exclusive.
+
+The modular multiply is the shared 16-bit-limb primitive (fieldops); the
+Fp4 multiply (:func:`_emul_limb`) is the same schoolbook ``x^4 = W_EXT``
+reduction as ``field.emul``.  Modular arithmetic is exact, so both give
+the same canonical representatives bit for bit.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from .. import pallas_call
 from ...core.field import W_EXT
 from ..fieldops.fieldops import addmod, mulmod_limb
 
 _U32 = jnp.uint32
+LANES = 128
 
 
-def _block_scan_kernel(x_ref, prefix_ref, total_ref):
-    """Exclusive prefix products within one block (log-step doubling)."""
-    x = x_ref[...]                       # (block,)
-    n = x.shape[0]
-    # inclusive scan via logarithmic shifts (Hillis-Steele in VMEM)
-    acc = x
-    shift = 1
-    while shift < n:
-        shifted = jnp.concatenate(
-            [jnp.ones((shift,), _U32), acc[:-shift]])
-        acc = mulmod_limb(acc, shifted)
-        shift *= 2
-    total_ref[...] = acc[-1:]
-    # exclusive = inclusive shifted right with leading 1
-    prefix_ref[...] = jnp.concatenate([jnp.ones((1,), _U32), acc[:-1]])
-
-
-def _apply_offset_kernel(prefix_ref, offset_ref, o_ref):
-    off = offset_ref[...]
-    o_ref[...] = mulmod_limb(prefix_ref[...],
-                             jnp.broadcast_to(off, prefix_ref.shape))
-
-
-def grand_product(x: jnp.ndarray, block: int = 256,
-                  interpret: bool = True) -> jnp.ndarray:
-    """Exclusive running product of (n,) BabyBear elements, n % block == 0."""
-    n = x.shape[0]
-    block = min(block, n)
-    assert n % block == 0
-    nb = n // block
-    prefixes, totals = pl.pallas_call(
-        _block_scan_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((block,), lambda i: (i,)),
-                   pl.BlockSpec((1,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((n,), _U32),
-                   jax.ShapeDtypeStruct((nb,), _U32)],
-        interpret=interpret,
-    )(x.astype(_U32))
-    # tiny host-side exclusive scan over block totals (nb elements)
-    from ...core import field as F
-    incl = jax.lax.associative_scan(F.fmul, totals)
-    offsets = jnp.concatenate([jnp.ones((1,), _U32), incl[:-1]])
-    out = pl.pallas_call(
-        _apply_offset_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,)),
-                  pl.BlockSpec((1,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), _U32),
-        interpret=interpret,
-    )(prefixes, offsets)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# Fp4 variant — the prover's phase-2 running products
-# ---------------------------------------------------------------------------
 def _emul_limb(a, b):
-    """Schoolbook Fp4 multiply (reduction x^4 = W_EXT) on (..., 4) lanes,
-    from the 16-bit-limb primitives — mirrors ``field.emul`` term for term,
-    so the result is the same canonical representative bit for bit."""
-    a0, a1, a2, a3 = a[..., 0], a[..., 1], a[..., 2], a[..., 3]
-    b0, b1, b2, b3 = b[..., 0], b[..., 1], b[..., 2], b[..., 3]
-
-    def m(x, y):
-        return mulmod_limb(x, y)
+    """Schoolbook Fp4 multiply (reduction x^4 = W_EXT) on 4-tuples of
+    coefficient planes — mirrors ``field.emul`` term for term."""
+    a0, a1, a2, a3 = a
+    b0, b1, b2, b3 = b
+    m = mulmod_limb
 
     def mw(x):
         return mulmod_limb(jnp.full_like(x, W_EXT), x)
@@ -108,73 +52,93 @@ def _emul_limb(a, b):
     c2 = addmod(addmod(m(a0, b2), m(a1, b1)), addmod(m(a2, b0),
                                                      mw(m(a3, b3))))
     c3 = addmod(addmod(m(a0, b3), m(a1, b2)), addmod(m(a2, b1), m(a3, b0)))
-    return jnp.stack([c0, c1, c2, c3], axis=-1)
+    return (c0, c1, c2, c3)
 
 
-def _ext_ones(k):
-    """(k, 4) multiplicative identities [1, 0, 0, 0]."""
-    return jnp.zeros((k, 4), _U32).at[:, 0].set(1)
+def _mul_limb(a, b):
+    return (mulmod_limb(a[0], b[0]),)
 
 
-def _block_scan_ext_kernel(x_ref, prefix_ref, total_ref):
-    """Exclusive Fp4 prefix products within one block (log-step doubling).
+def _scan_kernel(x_ref, o_ref, carry_ref, *, mul):
+    """x_ref/o_ref: (k, block_rows, 128); carry_ref: (k, 1, 128).
 
-    The doubling runs as a ``fori_loop`` with a dynamic-slice shift rather
-    than a python-unrolled concatenate chain: the Fp4 limb-multiply graph is
-    large, and unrolling it log2(block) times made XLA compilation take
-    minutes per shape — the loop traces it exactly once."""
-    x = x_ref[...]                       # (block, 4)
-    n = x.shape[0]
-    ones_n = _ext_ones(n)
-    n_steps = (n - 1).bit_length()       # shifts 1, 2, ..., >= n/2
+    One ``fori_loop`` holds the only multiply, so the (large) Fp4 multiply
+    is traced and compiled once.  Its steps: fold the carry into the first
+    element; 7 lane-doubling steps (inclusive product along each row, whose
+    last lane is broadcast as the row total); log2(block_rows) sublane-
+    doubling steps over the row totals; one step scaling each row by the
+    product of the rows before it."""
+    k, rows, _ = x_ref.shape
+    one = (1,) + (0,) * (k - 1)          # the multiplicative identity
+    lane_steps = LANES.bit_length() - 1
+    row_steps = rows.bit_length() - 1
 
-    def body(k, acc):
-        shift = jnp.left_shift(jnp.int32(1), k)
-        # shifted[i] = 1 for i < shift else acc[i - shift]
-        full = jnp.concatenate([ones_n, acc], axis=0)
-        shifted = jax.lax.dynamic_slice(full, (n - shift, jnp.int32(0)),
-                                        (n, 4))
-        return _emul_limb(acc, shifted)
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        for c in range(k):
+            carry_ref[c] = jnp.full(carry_ref.shape[1:], one[c], _U32)
 
-    acc = jax.lax.fori_loop(0, n_steps, body, x)
-    total_ref[...] = acc[-1:]
-    prefix_ref[...] = jnp.concatenate([_ext_ones(1), acc[:-1]], axis=0)
+    shape = (rows, LANES)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    carry = tuple(jnp.broadcast_to(carry_ref[c], shape) for c in range(k))
+
+    def ones():
+        return tuple(jnp.full(shape, o, _U32) for o in one)
+
+    def shifted(v, by, axis, idx):
+        """v moved `by` places up `axis`, the vacated places set to one."""
+        return tuple(jnp.where(idx < by, o, pltpu.roll(a, by, axis))
+                     for a, o in zip(v, ones()))
+
+    def pick(flag, a, b):
+        return tuple(jnp.where(flag, u, v) for u, v in zip(a, b))
+
+    def row_totals(v):
+        return tuple(jnp.broadcast_to(a[:, LANES - 1:], shape) for a in v)
+
+    def step(s, state):
+        acc, tot = state
+        on_lanes = s <= lane_steps
+        on_rows = (s > lane_steps) & (s <= lane_steps + row_steps)
+        by_lane = jnp.left_shift(1, jnp.clip(s - 1, 0, lane_steps - 1))
+        by_row = jnp.left_shift(1, jnp.clip(s - 1 - lane_steps, 0,
+                                            max(row_steps - 1, 0)))
+        origin = (lane == 0) & (row == 0)
+        b = pick(s == 0, pick(origin, carry, ones()),
+                 pick(on_lanes, shifted(acc, by_lane, 1, lane),
+                      pick(on_rows, shifted(tot, by_row, 0, row),
+                           shifted(tot, 1, 0, row))))
+        r = mul(pick(on_rows, tot, acc), b)
+        new_acc = pick(on_rows, acc, r)
+        new_tot = pick(on_rows, r, pick(on_lanes, row_totals(r), tot))
+        return new_acc, new_tot
+
+    x = tuple(x_ref[c] for c in range(k))
+    incl, tot = jax.lax.fori_loop(0, lane_steps + row_steps + 2, step,
+                                  (x, row_totals(x)))
+    # exclusive = inclusive moved one place on; position (0, 0) is the carry
+    first = pick(row == 0, carry, shifted(tot, 1, 0, row))
+    for c in range(k):
+        o_ref[c] = jnp.where(lane == 0, first[c], pltpu.roll(incl[c], 1, 1))
+        carry_ref[c] = tot[c][rows - 1:]
 
 
-def _apply_offset_ext_kernel(prefix_ref, offset_ref, o_ref):
-    off = offset_ref[...]                # (1, 4)
-    prefix = prefix_ref[...]             # (block, 4)
-    o_ref[...] = _emul_limb(prefix, jnp.broadcast_to(off, prefix.shape))
-
-
-def grand_product_ext(x: jnp.ndarray, block: int = 256,
-                      interpret: bool = True) -> jnp.ndarray:
-    """Exclusive running product of (n, 4) Fp4 elements, n % block == 0."""
-    n = x.shape[0]
-    block = min(block, n)
-    assert n % block == 0
-    nb = n // block
-    prefixes, totals = pl.pallas_call(
-        _block_scan_ext_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((block, 4), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((block, 4), lambda i: (i, 0)),
-                   pl.BlockSpec((1, 4), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n, 4), _U32),
-                   jax.ShapeDtypeStruct((nb, 4), _U32)],
+def exclusive_scan(planes: jnp.ndarray, block_rows: int, ext: bool,
+                   interpret: bool = True) -> jnp.ndarray:
+    """Exclusive running product over planes (k, rows, 128) in row-major
+    position order; rows % block_rows == 0, block_rows a power of two
+    >= 8.  ``ext`` selects Fp4 (k = 4) over base-field (k = 1) products."""
+    k, rows, _ = planes.shape
+    block = (k, block_rows, LANES)
+    return pallas_call(
+        functools.partial(_scan_kernel, mul=_emul_limb if ext else _mul_limb),
+        grid=(rows // block_rows,),
+        in_specs=[pl.BlockSpec(block, lambda i: (0, i, 0))],
+        out_specs=pl.BlockSpec(block, lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct(planes.shape, _U32),
+        scratch_shapes=[pltpu.VMEM((k, 1, LANES), _U32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(x.astype(_U32))
-    # tiny host-side exclusive scan over block totals (nb elements)
-    from ...core import field as F
-    incl = jax.lax.associative_scan(F.emul, totals, axis=0)
-    offsets = jnp.concatenate([_ext_ones(1), incl[:-1]], axis=0)
-    out = pl.pallas_call(
-        _apply_offset_ext_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((block, 4), lambda i: (i, 0)),
-                  pl.BlockSpec((1, 4), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block, 4), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 4), _U32),
-        interpret=interpret,
-    )(prefixes, offsets)
-    return out
+    )(planes.astype(_U32))

@@ -1,11 +1,13 @@
-"""jit'd wrapper + shape adapter for the Poseidon-like permutation kernel.
+"""Shape adapter for the Poseidon-like permutation kernel.
 
-The raw kernel (``poseidon.permute``) wants a flat ``(n, 16)`` batch with
-``n`` a multiple of its VMEM block.  Circuit-sized callers (Merkle level
-builds, sponge absorbs) show up with arbitrary leading batch shapes and
-non-tile-multiple row counts, so :func:`permute` here flattens, zero-pads
-the batch up to the tile, runs the kernel, and slices the padding back off
-— padding rows are independent states, so they cannot perturb real lanes.
+The raw kernel (``poseidon.permute``) wants transposed ``(16, n)`` states
+with ``n`` a multiple of its block.  Callers (Merkle level builds, sponge
+absorbs, lane stacks) arrive with any leading batch shape and row count,
+so :func:`permute` flattens and zero-pads the batch up to a size bucket —
+padding rows are independent states, so they cannot perturb real lanes —
+and slices the padding back off.  Buckets are powers of two of at least
+one tile, so a Merkle build, which halves its batch at every level, meets
+one compiled kernel per level and every batch below a tile shares one.
 """
 from __future__ import annotations
 
@@ -17,19 +19,28 @@ import jax.numpy as jnp
 from . import poseidon as K
 
 _U32 = jnp.uint32
-TILE = 64          # kernel batch block (states per grid step)
+TILE = 256         # states per grid step (the lane axis of a kernel block)
+
+
+def bucket(n: int) -> int:
+    """Padded batch size the kernel is compiled for: a power of two >= TILE."""
+    return max(TILE, 1 << (n - 1).bit_length())
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
+def _permute_bucket(flat, interpret: bool):
+    return K.permute(flat.T, block=TILE, interpret=interpret).T
+
+
 def permute(states, interpret: bool = True):
     """Backend entry point: (..., 16) states, any batch shape/count."""
+    states = jnp.asarray(states).astype(_U32)
     shape = states.shape
-    flat = states.reshape(-1, 16).astype(_U32)
+    flat = states.reshape(-1, 16)
     n = flat.shape[0]
     if n == 0:
-        return states.astype(_U32)
-    pad = (-n) % TILE
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad, 16), _U32)], axis=0)
-    out = K.permute(flat, block=TILE, interpret=interpret)
-    return out[:n].reshape(shape)
+        return states
+    size = bucket(n)
+    if size != n:
+        flat = jnp.pad(flat, ((0, size - n), (0, 0)))
+    return _permute_bucket(flat, interpret)[:n].reshape(shape)

@@ -1,11 +1,13 @@
 """Pallas kernel for the Poseidon2-like permutation over BabyBear.
 
-TPU mapping: a (block, 16) batch of sponge states lives in VMEM; each of the
-22 rounds does (sbox ->) a 16x16 field matmul. The modular matmul is
-elementwise 16-bit-limb products broadcast to (block, 16, 16) followed by a
-log-tree modular reduction — on real TPU the i32 products ride the VPU while
-the data layout matches the MXU tiling for a fused int8/int16 path (see
-EXPERIMENTS.md §Perf for the measured schedule discussion).
+TPU mapping: states are laid out transposed, ``(16, n)`` — lane ``j`` of
+every state sits on sublane row ``j`` and the batch runs along the 128-wide
+lane axis, so each ``(16, block)`` tile fills whole vregs.  A round adds its
+constants, applies the S-box (all rows, or row 0 in a partial round) and
+multiplies by the 16x16 MDS matrix as 16 row-broadcast multiply-adds with
+the 16-bit-limb modular multiply.  The 22 rounds run as three
+``fori_loop``s over the round-constant table, so the round body is traced
+and compiled once per round kind instead of 22 times.
 """
 from __future__ import annotations
 
@@ -13,6 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import pallas_call
 from ...core import hashing as H
 from ..fieldops.fieldops import addmod, mulmod_limb
 
@@ -25,61 +28,52 @@ def _sbox(x):
     return mulmod_limb(mulmod_limb(x4, x2), x)
 
 
-def _matmul_mod(state, mds):
-    """state (bt, 16) x mds (16, 16) with limb products + tree addmod."""
-    prod = mulmod_limb(
-        jnp.broadcast_to(state[:, :, None], state.shape + (16,)),
-        jnp.broadcast_to(mds[None, :, :], state.shape + (16,)))
-    acc = prod  # (bt, 16, 16); reduce axis=1 in log steps
-    k = 16
-    while k > 1:
-        k //= 2
-        acc = addmod(acc[:, :k, :], acc[:, k:2 * k, :])
-    return acc[:, 0, :]
+def _matmul_mod(x, mds_ref):
+    """out[j] = sum_i x[i] * mds[i][j]; x (16, bt), mds_ref[i] (16, 1)."""
+    acc = None
+    for i in range(H.WIDTH):
+        term = mulmod_limb(jnp.broadcast_to(x[i:i + 1], x.shape),
+                           jnp.broadcast_to(mds_ref[i], x.shape))
+        acc = term if acc is None else addmod(acc, term)
+    return acc
 
 
 def _permute_kernel(x_ref, rc_ref, mds_ref, o_ref):
-    x = x_ref[...]
-    rc = rc_ref[...]
-    mds = mds_ref[...]
+    row0 = jax.lax.broadcasted_iota(jnp.int32, x_ref.shape, 0) == 0
+
+    def full_round(r, x):
+        x = addmod(x, jnp.broadcast_to(rc_ref[r], x.shape))
+        return _matmul_mod(_sbox(x), mds_ref)
+
+    def partial_round(r, x):
+        x = addmod(x, jnp.broadcast_to(rc_ref[r], x.shape))
+        x = jnp.where(row0, jnp.broadcast_to(_sbox(x[0:1]), x.shape), x)
+        return _matmul_mod(x, mds_ref)
+
     half = H.FULL_ROUNDS // 2
-    r = 0
-    for _ in range(half):
-        x = addmod(x, jnp.broadcast_to(rc[r][None], x.shape))
-        x = _sbox(x)
-        x = _matmul_mod(x, mds)
-        r += 1
-    for _ in range(H.PARTIAL_ROUNDS):
-        x = addmod(x, jnp.broadcast_to(rc[r][None], x.shape))
-        lane0 = _sbox(x[:, :1])
-        x = jnp.concatenate([lane0, x[:, 1:]], axis=1)
-        x = _matmul_mod(x, mds)
-        r += 1
-    for _ in range(half):
-        x = addmod(x, jnp.broadcast_to(rc[r][None], x.shape))
-        x = _sbox(x)
-        x = _matmul_mod(x, mds)
-        r += 1
-    o_ref[...] = x
+    mid = half + H.PARTIAL_ROUNDS
+    x = jax.lax.fori_loop(0, half, full_round, x_ref[...])
+    x = jax.lax.fori_loop(half, mid, partial_round, x)
+    o_ref[...] = jax.lax.fori_loop(mid, mid + half, full_round, x)
 
 
-def permute(states: jnp.ndarray, block: int = 64,
+def permute(states_t: jnp.ndarray, block: int,
             interpret: bool = True) -> jnp.ndarray:
-    """states: (n, 16) -> (n, 16)."""
-    n = states.shape[0]
-    block = min(block, n)
+    """states_t: (16, n) transposed states, n % block == 0 -> (16, n)."""
+    n = states_t.shape[1]
     assert n % block == 0
     mds, rc = H._params()
-    out = pl.pallas_call(
+    rc3 = jnp.asarray(rc[:, :, None])          # (rounds, 16, 1) columns
+    mds3 = jnp.asarray(mds[:, :, None])        # row i of mds as a column
+    return pallas_call(
         _permute_kernel,
         grid=(n // block,),
         in_specs=[
-            pl.BlockSpec((block, 16), lambda i: (i, 0)),
-            pl.BlockSpec(rc.shape, lambda i: (0, 0)),
-            pl.BlockSpec((16, 16), lambda i: (0, 0)),
+            pl.BlockSpec((H.WIDTH, block), lambda i: (0, i)),
+            pl.BlockSpec(rc3.shape, lambda i: (0, 0, 0)),
+            pl.BlockSpec(mds3.shape, lambda i: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((block, 16), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 16), _U32),
+        out_specs=pl.BlockSpec((H.WIDTH, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((H.WIDTH, n), _U32),
         interpret=interpret,
-    )(states.astype(_U32), jnp.asarray(rc), jnp.asarray(mds))
-    return out
+    )(states_t.astype(_U32), rc3, mds3)

@@ -68,12 +68,22 @@ def test_use_nests_and_restores(monkeypatch):
 def test_probe_reports_cleanly():
     ok, reason = backend.probe("pallas-interpret")
     assert ok, reason
-    # the compiled backend needs an accelerator; on CPU hosts the probe
-    # must answer False with a reason, never raise
+    # the compiled backend needs a TPU: on CPU hosts the probe must answer
+    # False with a reason, never raise; on a TPU it must work
     import jax
     ok, reason = backend.probe("pallas")
     if jax.default_backend() == "cpu":
         assert not ok and reason
+    if jax.default_backend() == "tpu":
+        assert ok, reason
+
+
+def test_require_fails_loudly_where_the_backend_cannot_run():
+    import jax
+    assert backend.require("pallas-interpret").name == "pallas-interpret"
+    if jax.default_backend() == "cpu":
+        with pytest.raises(backend.BackendUnavailableError, match="pallas"):
+            backend.require("pallas")
 
 
 # ---------------------------------------------------------------------------

@@ -43,6 +43,25 @@ def test_batch_inverse():
     np.testing.assert_array_equal(np.asarray(prod), expect)
 
 
+@pytest.mark.parametrize("shape", [(1,), (63,), (64,), (65,), (3, 1000),
+                                   (2, 5, 129)])
+def test_batch_inverse_matches_elementwise_inverse(shape):
+    """The blocked batch inverse equals a^(P-2) elementwise at lengths
+    around and across its block, with leading axes and zeros."""
+    rng = np.random.default_rng(len(shape) * 1000 + shape[-1])
+    a = rng.integers(0, F.P, size=shape).astype(np.uint32)
+    a.reshape(-1)[::7] = 0
+    got = np.asarray(F.fbatch_inv(jnp.asarray(a)))
+    want = np.asarray(F.finv(jnp.asarray(np.where(a == 0, 1, a))))
+    np.testing.assert_array_equal(got, np.where(a == 0, 0, want))
+    ext = rng.integers(0, F.P, size=shape + (4,)).astype(np.uint32)
+    ext.reshape(-1, 4)[::5] = 0
+    got = np.asarray(F.ebatch_inv(jnp.asarray(ext)))
+    zero = (ext == 0).all(-1, keepdims=True)
+    want = np.asarray(F.einv(jnp.asarray(np.where(zero, F.EXT_ONE, ext))))
+    np.testing.assert_array_equal(got, np.where(zero, 0, want))
+
+
 @given(st.integers(0, 2**32), st.integers(0, 2**32))
 @settings(max_examples=30, deadline=None)
 def test_ext_mul_matches_poly_mul(seed_a, seed_b):
@@ -98,3 +117,20 @@ def test_epow_matches_repeated_mul():
     for e in range(8):
         np.testing.assert_array_equal(np.asarray(F.epow(a, e)), np.asarray(acc))
         acc = F.emul(acc, a)
+
+
+def test_mod_p_matches_remainder_on_uint64():
+    """Barrett reduction == the 64-bit remainder, edges and random values."""
+    rng = np.random.default_rng(3)
+    p = F.P
+    edges = np.array([0, 1, p - 1, p, p + 1, 2 * p - 1, 2 * p, (p - 1) ** 2,
+                      2 ** 31, 2 ** 32 - 1, 2 ** 62, 2 ** 63 - 1, 2 ** 63,
+                      2 ** 64 - 2, 2 ** 64 - 1, (2 ** 64 - 1) // p * p,
+                      (2 ** 64 - 1) // p * p - 1], np.uint64)
+    wide = (rng.integers(0, 2 ** 32, 4096, dtype=np.uint64) << np.uint64(32)) \
+        | rng.integers(0, 2 ** 32, 4096, dtype=np.uint64)
+    prods = rng.integers(0, p, 4096).astype(np.uint64) * \
+        rng.integers(0, p, 4096).astype(np.uint64)
+    x = np.concatenate([edges, wide, prods])
+    got = np.asarray(F.mod_p(jnp.asarray(x)))
+    np.testing.assert_array_equal(got, x % np.uint64(p))

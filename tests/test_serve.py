@@ -8,6 +8,7 @@ host telemetry).  Everything the service does — shape routing, lane
 padding, deadline flushing — must be invisible in the artifact.
 """
 import dataclasses
+import textwrap
 import threading
 import time
 
@@ -389,3 +390,61 @@ def test_lde_cache_concurrent_access(db, tiny_cfg):
     assert all(k is got[0] for k in got)
     np.testing.assert_array_equal(np.asarray(got[0].fixed_lde),
                                   np.asarray(solo_keys.fixed_lde))
+
+
+# ---------------------------------------------------------------------------
+# lanes spread over a four-device mesh (subprocess: the virtual-device XLA
+# flag must be set before JAX starts, and must not leak into other tests)
+# ---------------------------------------------------------------------------
+_FOUR_DEVICE_SERVICE = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax
+    from repro.core import prover as pv
+    from repro.core.session import ZKGraphSession
+    from repro.graphdb import ldbc
+    from repro.serve import ProofService
+    from repro.serve.placement import Placement, serving_mesh
+
+    assert len(jax.devices()) == 4, jax.devices()
+    db = ldbc.generate(n_knows=96, n_persons=24, n_comments=64, seed=11)
+    cfg = pv.ProverConfig(blowup=4, n_queries=4, fri_final_size=16,
+                          backend="pallas-interpret")
+    owner = ZKGraphSession(db, cfg)
+    params = [dict(message=(1 << 20) + m) for m in range(8)]
+
+    def canonical(bundle):
+        for sp in bundle.steps:
+            sp.proof.timings = {}
+        return bundle.to_bytes()
+
+    solo = [canonical(owner.prove("IS5", p)) for p in params]
+    placement = Placement(serving_mesh())
+    assert placement.lane_parallelism == 4
+    with ProofService(owner, max_batch=8, flush_interval=0.5,
+                      placement=placement) as svc:
+        futures = [svc.submit("IS5", p) for p in params]
+        served = [canonical(f.result(timeout=400)) for f in futures]
+        batches = svc.stats()["counters"]["batches"]
+    assert batches == 1, batches
+    assert served == solo
+    print("OK")
+""")
+
+
+def test_service_lanes_over_four_devices_match_solo():
+    """``Placement(serving_mesh())`` on four (virtual CPU) devices: eight
+    IS5 lanes proved in one batch, spread over the devices with the Pallas
+    kernels (interpret mode) run per device, are wire-byte-identical to
+    solo one-device proves."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
+    out = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_SERVICE],
+                         capture_output=True, text=True, timeout=500, env=env)
+    assert out.returncode == 0 and "OK" in out.stdout, \
+        out.stdout[-2000:] + out.stderr[-4000:]
