@@ -578,20 +578,13 @@ def serving(rows: int = 128):
     the same run with warm jit caches.  Lane-batched proving amortizes the
     per-dispatch overhead every solo prove pays, so queries/sec should grow
     with concurrency while each bundle stays wire-byte-identical to its
-    solo prove (asserted below, timings aside).  Emits
+    solo prove (asserted below).  Emits
     ``BENCH_serving.json``; latency leaves are gated by
     ``benchmarks/check_regression.py`` against baselines/serving.json."""
     import json
     import time
 
-    from repro.core.session import ProofBundle
     from repro.serve import ProofService
-
-    def strip_timings(raw: bytes) -> bytes:
-        bundle = ProofBundle.from_bytes(raw)
-        for sp in bundle.steps:
-            sp.proof.timings = {}
-        return bundle.to_bytes()
 
     db = db_with_rows(rows)
     session = ZKGraphSession(db, BENCH_CFG)
@@ -630,8 +623,7 @@ def serving(rows: int = 128):
             lambda n=conc: [session.prove(q, p) for q, p in queries[:n]])
         bundles, lat, stats, svc_us = serve(conc)
         for got, want in zip(bundles, seq_bundles):
-            assert strip_timings(got.to_bytes()) == \
-                strip_timings(want.to_bytes()), \
+            assert got.to_bytes() == want.to_bytes(), \
                 "serviced bundle bytes diverged from the sequential prover"
         qps = conc / (svc_us / 1e6)
         seq_qps = conc / (seq_us / 1e6)

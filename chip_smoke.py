@@ -17,7 +17,7 @@ backend, on LDBC-shaped tables of the smallest size the paper proved
   verifier must accept the bundle's wire bytes and reject them with one
   byte flipped;
 * prove IS5 again under the ``ref`` backend on the chip: its wire bytes
-  (timings stripped) must equal the ``pallas`` bytes;
+  must equal the ``pallas`` bytes;
 * serve eight IS5 submissions through ``ProofService``: their bytes must
   equal solo proves.
 
@@ -222,13 +222,6 @@ def proved_result(qname: str, result: dict) -> dict:
     raise KeyError(qname)
 
 
-def canonical_bytes(bundle) -> bytes:
-    """Wire bytes with the wall-clock timings diagnostic stripped."""
-    for step in bundle.steps:
-        step.proof.timings = {}
-    return bundle.to_bytes()
-
-
 def flip_one_byte(raw: bytes) -> bytes:
     i = len(raw) // 2
     return raw[:i] + bytes([raw[i] ^ 0x01]) + raw[i + 1:]
@@ -283,14 +276,14 @@ def run_one_chip(args) -> None:
                        warm.steps[0].proof.timings.items())
     log(f"warm prove IS5: {secs:.3f} s ({note}; prover phases, s: "
         f"{phases})")
-    pallas_raw = canonical_bytes(warm)
-    check(pallas_raw == canonical_bytes(bundles["IS5"]),
+    pallas_raw = warm.to_bytes()
+    check(pallas_raw == bundles["IS5"].to_bytes(),
           "two pallas proves of one IS5 query differ")
 
     ref_cfg = dataclasses.replace(owner.cfg, backend="ref")
     ref_owner = ZKGraphSession(db, ref_cfg, commitments=owner.commitments)
     ref_bundle, secs, note = timed(ref_owner.prove, "IS5", params["IS5"])
-    check(canonical_bytes(ref_bundle) == pallas_raw,
+    check(ref_bundle.to_bytes() == pallas_raw,
           "ref and pallas IS5 wire bytes differ on the chip")
     log(f"ref == pallas: IS5 wire bytes identical on the chip "
         f"({len(pallas_raw)} bytes; ref prove {secs:.3f} s, {note})")
@@ -299,7 +292,7 @@ def run_one_chip(args) -> None:
     solo, solo_secs, _ = timed(lambda: [owner.prove("IS5", p)
                                         for p in params["serve"]])
     for p, a, b in zip(params["serve"], served, solo):
-        check(canonical_bytes(a) == canonical_bytes(b),
+        check(a.to_bytes() == b.to_bytes(),
               f"ProofService bytes != solo prove for IS5 {p}")
         check(verifier.verify(a), f"served IS5 {p} rejected")
     log(f"serve: {SERVE_LANES} IS5 submissions through ProofService "
@@ -338,7 +331,7 @@ def run_four_chips(args) -> None:
           f"expected one batch of {SERVE_LANES} lanes, got "
           f"{stats['counters']['batches']}")
     for p, a, b in zip(params, served, solo):
-        check(canonical_bytes(a) == canonical_bytes(b),
+        check(a.to_bytes() == b.to_bytes(),
               f"four-chip ProofService bytes != solo prove for IS5 {p}")
     log(f"four chips: {SERVE_LANES} IS5 lanes in one batch over {FOUR} "
         f"devices ({secs:.3f} s, {note}) byte-identical to solo one-device "

@@ -157,17 +157,6 @@ def query_queue(db, n):
 # ---------------------------------------------------------------------------
 # shared helpers
 # ---------------------------------------------------------------------------
-def _strip_timings(raw: bytes) -> bytes:
-    """Re-encode bundle bytes with per-step prover timings zeroed: timings
-    are host-side telemetry carried in the wire format, and the only field
-    where a batched and a solo prove may legitimately differ."""
-    from repro.core.session import ProofBundle
-    bundle = ProofBundle.from_bytes(raw)
-    for sp in bundle.steps:
-        sp.proof.timings = {}
-    return bundle.to_bytes()
-
-
 def atomic_write(path: Path, data: bytes) -> None:
     tmp = path.with_suffix(path.suffix + ".tmp")
     tmp.write_bytes(data)
@@ -288,11 +277,11 @@ def run_owner(args) -> None:
         print(f"[owner] served {len(pending)} queries, mean batch "
               f"occupancy {occupancy['mean']:.2f}", flush=True)
         # byte-for-byte spot check: re-prove one serviced query solo and
-        # compare wire bytes (timings are telemetry, not proof material)
+        # compare wire bytes
         i0, kind0, params0 = pending[0]
         serviced = (spool / f"q{i0}.bin").read_bytes()
         solo = session.prove(kind0, params0)
-        assert _strip_timings(serviced) == _strip_timings(solo.to_bytes()), \
+        assert serviced == solo.to_bytes(), \
             "serviced bundle bytes diverged from the solo prover"
         print(f"[owner] q{i0} re-proven solo: bytes identical", flush=True)
 

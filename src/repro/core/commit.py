@@ -23,6 +23,7 @@ from dataclasses import dataclass, field as dc_field
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import backend as be
 from . import field as F
 from . import merkle
@@ -219,13 +220,15 @@ def publish_commitments(db: GraphDB, cfg: pv.ProverConfig = None, *,
     manifest = CommitmentManifest(
         MANIFEST_VERSION, int(db.n_nodes),
         {name: len(t) for name, t in db.tables.items()}, {})
-    for desc in descs:
-        cols = tables.base_table_cols(db, desc)
-        sizes = table_sizes(db, cols.shape[1])
-        manifest.tables[desc] = TableGeometry(
-            desc, int(cols.shape[0]), int(cols.shape[1]), tuple(sizes),
-            tables.table_columns(desc))
-        for n_rows in sizes:
-            manifest.roots[(desc, n_rows)] = data_root(cols, n_rows, cfg,
-                                                       desc=desc)
+    with obs.span("zkg.commit", tables=len(descs)) as sp:
+        for desc in descs:
+            cols = tables.base_table_cols(db, desc)
+            sizes = table_sizes(db, cols.shape[1])
+            manifest.tables[desc] = TableGeometry(
+                desc, int(cols.shape[0]), int(cols.shape[1]), tuple(sizes),
+                tables.table_columns(desc))
+            for n_rows in sizes:
+                manifest.roots[(desc, n_rows)] = data_root(cols, n_rows, cfg,
+                                                           desc=desc)
+        sp.set(roots=len(manifest.roots))
     return manifest

@@ -8,13 +8,13 @@ Pipeline (paper §III-B, adapted per DESIGN.md §2):
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field as dc_field
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import backend as be
 from . import field as F
 from . import fri as fri_mod
@@ -64,6 +64,8 @@ class Proof:
     openings: dict                 # (kind, idx, rot) -> np (4,) for committed kinds
     fri_proof: fri_mod.FriProof
     tree_openings: dict            # tree name -> (rows, paths) at [q, q+half]
+    # seconds by phase, from the prover's spans (repro.obs); in memory
+    # only, never serialized
     timings: dict = dc_field(default_factory=dict)
 
     def size_fields(self) -> int:
@@ -335,182 +337,180 @@ def _prove_impl(keys: Keys, advice_np: np.ndarray, instance_np: np.ndarray,
     circuit, cfg = keys.circuit, keys.cfg
     n, B = circuit.n_rows, cfg.blowup
     nl = n * B
-    t0 = time.perf_counter()
-    timings = {}
+    phase = obs.Phases("zkg.prove", lanes=1)
 
-    if data_np is None:
-        data_np = np.zeros((0, n), np.uint32)
-    auto_multiplicities(circuit, data_np, advice_np, instance_np)
-    advice = jnp.asarray(advice_np.astype(np.uint32))
-    data = jnp.asarray(data_np.astype(np.uint32)) if circuit.n_data \
-        else jnp.zeros((0, n), _U32)
-    inst = jnp.asarray(instance_np.astype(np.uint32)) if circuit.n_instance \
-        else jnp.zeros((0, n), _U32)
+    with phase("commit_advice") as sp:
+        if data_np is None:
+            data_np = np.zeros((0, n), np.uint32)
+        auto_multiplicities(circuit, data_np, advice_np, instance_np)
+        advice = jnp.asarray(advice_np.astype(np.uint32))
+        data = jnp.asarray(data_np.astype(np.uint32)) if circuit.n_data \
+            else jnp.zeros((0, n), _U32)
+        inst = jnp.asarray(instance_np.astype(np.uint32)) if circuit.n_instance \
+            else jnp.zeros((0, n), _U32)
 
-    tx = Transcript(label)
-    tx.absorb(circuit.digest_seed())
-    if circuit.n_instance:
-        # bind public I/O by a Merkle root (one digest, not O(N) sponge blocks)
-        tx.absorb_digest(np.asarray(merkle.commit(inst.T).root))
+        tx = Transcript(label)
+        tx.absorb(circuit.digest_seed())
+        if circuit.n_instance:
+            # bind public I/O by a Merkle root (one digest, not O(N) sponge blocks)
+            tx.absorb_digest(np.asarray(merkle.commit(inst.T).root))
 
-    # --- phase 0: commit the dataset (the declared-DB binding) --------------
-    data_coeffs = poly.intt(data) if circuit.n_data else data
-    data_lde = _lde(data, B, cfg.shift)
-    data_tree = merkle.commit(data_lde.T) if circuit.n_data else None
-    data_root = np.asarray(data_tree.root) if data_tree else np.zeros(8, np.uint32)
-    tx.absorb_digest(data_root)
+        # --- phase 0: commit the dataset (the declared-DB binding) --------------
+        data_coeffs = poly.intt(data) if circuit.n_data else data
+        data_lde = _lde(data, B, cfg.shift)
+        data_tree = merkle.commit(data_lde.T) if circuit.n_data else None
+        data_root = np.asarray(data_tree.root) if data_tree else np.zeros(8, np.uint32)
+        tx.absorb_digest(data_root)
 
-    # --- phase 1: commit advice -------------------------------------------
-    adv_coeffs = poly.intt(advice) if circuit.n_advice else advice
-    adv_lde = _lde(advice, B, cfg.shift)
-    adv_tree = merkle.commit(adv_lde.T) if circuit.n_advice else None
-    adv_root = np.asarray(adv_tree.root) if adv_tree else np.zeros(8, np.uint32)
-    tx.absorb_digest(adv_root)
-    timings["commit_advice"] = time.perf_counter() - t0
+        # --- phase 1: commit advice -------------------------------------------
+        adv_coeffs = poly.intt(advice) if circuit.n_advice else advice
+        adv_lde = _lde(advice, B, cfg.shift)
+        adv_tree = merkle.commit(adv_lde.T) if circuit.n_advice else None
+        adv_root = np.asarray(adv_tree.root) if adv_tree else np.zeros(8, np.uint32)
+        tx.absorb_digest(adv_root)
+        sp.sync(data_coeffs, adv_coeffs, data_lde, adv_lde)
 
     alpha = jnp.asarray(tx.challenge_ext())
     beta = jnp.asarray(tx.challenge_ext())
 
     # --- phase 2: ext columns ----------------------------------------------
-    t1 = time.perf_counter()
-    fixed_n = jnp.asarray(np.stack(circuit.fixed_cols)
-                          if circuit.fixed_cols else np.zeros((0, n), np.uint32))
+    with phase("phase2_ext") as sp:
+        fixed_n = jnp.asarray(np.stack(circuit.fixed_cols)
+                              if circuit.fixed_cols else np.zeros((0, n), np.uint32))
 
-    def getter_n(kind, idx, rot):
-        src = {FIXED: fixed_n, ADVICE: advice, INSTANCE: inst, DATA: data}[kind]
-        return jnp.roll(src[idx], -rot)
+        def getter_n(kind, idx, rot):
+            src = {FIXED: fixed_n, ADVICE: advice, INSTANCE: inst, DATA: data}[kind]
+            return jnp.roll(src[idx], -rot)
 
-    like_n = jnp.zeros(n, _U32)
-    ext_cols = build_ext_columns(circuit, getter_n, like_n, alpha, beta)
-    n_ext = circuit.n_ext
-    ext_base = ext_cols.transpose(0, 2, 1).reshape(n_ext * 4, n) if n_ext \
-        else jnp.zeros((0, n), _U32)
-    ext_coeffs = poly.intt(ext_base) if n_ext else ext_base
-    ext_lde = _lde(ext_base, B, cfg.shift)
-    ext_tree = merkle.commit(ext_lde.T) if n_ext else None
-    ext_root = np.asarray(ext_tree.root) if ext_tree else np.zeros(8, np.uint32)
-    tx.absorb_digest(ext_root)
-    timings["phase2_ext"] = time.perf_counter() - t1
+        like_n = jnp.zeros(n, _U32)
+        ext_cols = build_ext_columns(circuit, getter_n, like_n, alpha, beta)
+        n_ext = circuit.n_ext
+        ext_base = ext_cols.transpose(0, 2, 1).reshape(n_ext * 4, n) if n_ext \
+            else jnp.zeros((0, n), _U32)
+        ext_coeffs = poly.intt(ext_base) if n_ext else ext_base
+        ext_lde = _lde(ext_base, B, cfg.shift)
+        ext_tree = merkle.commit(ext_lde.T) if n_ext else None
+        ext_root = np.asarray(ext_tree.root) if ext_tree else np.zeros(8, np.uint32)
+        tx.absorb_digest(ext_root)
+        sp.sync(ext_coeffs, ext_lde)
 
     alpha_c = jnp.asarray(tx.challenge_ext())
 
     # --- quotient -----------------------------------------------------------
-    t2 = time.perf_counter()
-    fixed_lde, inst_lde = keys.fixed_lde, _lde(inst, B, cfg.shift)
+    with phase("quotient") as sp:
+        fixed_lde, inst_lde = keys.fixed_lde, _lde(inst, B, cfg.shift)
 
-    def getter_lde(kind, idx, rot):
-        src = {FIXED: fixed_lde, ADVICE: adv_lde, INSTANCE: inst_lde,
-               DATA: data_lde}[kind]
-        return jnp.roll(src[idx], -B * rot)
+        def getter_lde(kind, idx, rot):
+            src = {FIXED: fixed_lde, ADVICE: adv_lde, INSTANCE: inst_lde,
+                   DATA: data_lde}[kind]
+            return jnp.roll(src[idx], -B * rot)
 
-    def ext_getter_lde(col, rot):
-        comps = [jnp.roll(ext_lde[col * 4 + c], -B * rot) for c in range(4)]
-        return jnp.stack(comps, axis=-1)
+        def ext_getter_lde(col, rot):
+            comps = [jnp.roll(ext_lde[col * 4 + c], -B * rot) for c in range(4)]
+            return jnp.stack(comps, axis=-1)
 
-    like_lde = jnp.zeros(nl, _U32)
-    row0_lde = (getter_lde(FIXED, circuit.fixed_names.index("__row0"), 0)
-                if circuit.gps else like_lde)
+        like_lde = jnp.zeros(nl, _U32)
+        row0_lde = (getter_lde(FIXED, circuit.fixed_names.index("__row0"), 0)
+                    if circuit.gps else like_lde)
 
-    def ext_of_base_lde(v):
-        z = jnp.zeros(v.shape + (4,), _U32)
-        return z.at[..., 0].set(v)
+        def ext_of_base_lde(v):
+            z = jnp.zeros(v.shape + (4,), _U32)
+            return z.at[..., 0].set(v)
 
-    c_lde = combine_constraints(circuit, getter_lde, ext_getter_lde, alpha, beta,
-                                alpha_c, like_lde, BaseOps, ext_of_base_lde,
-                                row0_lde)
-    # Z_H(x_i) = x_i^N - 1 = shift^N * (w_nl^N)^i - 1: period-B sequence in i
-    wn = F.root_of_unity(nl)
-    ratio = pow(wn, n, F.P)
-    vals = np.empty(B, np.uint64)
-    acc = pow(cfg.shift, n, F.P)
-    for i in range(B):
-        vals[i] = (acc - 1) % F.P
-        acc = acc * ratio % F.P
-    zh = np.asarray([vals[i % B] for i in range(nl)], np.uint32)
-    zh_inv = F.fbatch_inv(jnp.asarray(zh))
-    q_evals = F.fmul(c_lde, zh_inv[:, None])
-    q_coeffs = poly.coset_coeffs(q_evals.T, cfg.shift)    # (4, NL)
-    q_segments = q_coeffs.reshape(4, B, n).transpose(1, 0, 2).reshape(B * 4, n)
-    q_lde = _lde_from_coeffs(q_segments, B, cfg.shift)
-    q_tree = merkle.commit(q_lde.T)
-    q_root = np.asarray(q_tree.root)
-    tx.absorb_digest(q_root)
-    timings["quotient"] = time.perf_counter() - t2
+        c_lde = combine_constraints(circuit, getter_lde, ext_getter_lde, alpha, beta,
+                                    alpha_c, like_lde, BaseOps, ext_of_base_lde,
+                                    row0_lde)
+        # Z_H(x_i) = x_i^N - 1 = shift^N * (w_nl^N)^i - 1: period-B sequence in i
+        wn = F.root_of_unity(nl)
+        ratio = pow(wn, n, F.P)
+        vals = np.empty(B, np.uint64)
+        acc = pow(cfg.shift, n, F.P)
+        for i in range(B):
+            vals[i] = (acc - 1) % F.P
+            acc = acc * ratio % F.P
+        zh = np.asarray([vals[i % B] for i in range(nl)], np.uint32)
+        zh_inv = F.fbatch_inv(jnp.asarray(zh))
+        q_evals = F.fmul(c_lde, zh_inv[:, None])
+        q_coeffs = poly.coset_coeffs(q_evals.T, cfg.shift)    # (4, NL)
+        q_segments = q_coeffs.reshape(4, B, n).transpose(1, 0, 2).reshape(B * 4, n)
+        q_lde = _lde_from_coeffs(q_segments, B, cfg.shift)
+        q_tree = merkle.commit(q_lde.T)
+        q_root = np.asarray(q_tree.root)
+        tx.absorb_digest(q_root)
+        sp.sync(q_segments, q_lde)
 
     # --- OOD openings --------------------------------------------------------
-    t3 = time.perf_counter()
-    z = jnp.asarray(tx.challenge_ext())
-    sched = opening_schedule(circuit, B)
-    coeff_src = {FIXED: keys.fixed_coeffs, INSTANCE: poly.intt(inst) if
-                 circuit.n_instance else inst, DATA: data_coeffs,
-                 ADVICE: adv_coeffs, "ext": ext_coeffs, "quotient": q_segments}
-    w_n = F.root_of_unity(n)
-    openings = {}
-    rots = sorted({r for (_, _, r) in sched})
-    for rot in rots:
-        zr = F.emul_fp(z, _U32(pow(w_n, rot, F.P)))
-        for kind in (FIXED, INSTANCE, DATA, ADVICE, "ext", "quotient"):
-            idxs = [i for (k, i, rr) in sched if k == kind and rr == rot]
-            if not idxs:
-                continue
-            # repeat a row up to a multiple of 8: one gather/eval shape
-            rows = idxs + idxs[:1] * ((-len(idxs)) % 8)
-            vals = poly.eval_at_ext(coeff_src[kind][jnp.asarray(rows)], zr)
-            for i, v in zip(idxs, np.asarray(vals)):
-                openings[(kind, i, rot)] = v
-    for key in sched:
-        tx.absorb(openings[key])
-    timings["ood_openings"] = time.perf_counter() - t3
+    with phase("ood_openings"):
+        z = jnp.asarray(tx.challenge_ext())
+        sched = opening_schedule(circuit, B)
+        coeff_src = {FIXED: keys.fixed_coeffs, INSTANCE: poly.intt(inst) if
+                     circuit.n_instance else inst, DATA: data_coeffs,
+                     ADVICE: adv_coeffs, "ext": ext_coeffs, "quotient": q_segments}
+        w_n = F.root_of_unity(n)
+        openings = {}
+        rots = sorted({r for (_, _, r) in sched})
+        for rot in rots:
+            zr = F.emul_fp(z, _U32(pow(w_n, rot, F.P)))
+            for kind in (FIXED, INSTANCE, DATA, ADVICE, "ext", "quotient"):
+                idxs = [i for (k, i, rr) in sched if k == kind and rr == rot]
+                if not idxs:
+                    continue
+                # repeat a row up to a multiple of 8: one gather/eval shape
+                rows = idxs + idxs[:1] * ((-len(idxs)) % 8)
+                vals = poly.eval_at_ext(coeff_src[kind][jnp.asarray(rows)], zr)
+                for i, v in zip(idxs, np.asarray(vals)):
+                    openings[(kind, i, rot)] = v
+        for key in sched:
+            tx.absorb(openings[key])
 
     # --- DEEP composition -----------------------------------------------------
-    t4 = time.perf_counter()
-    gamma = jnp.asarray(tx.challenge_ext())
-    pts = F.fmul(poly.domain_points(nl), _U32(cfg.shift))   # (NL,)
-    committed = [(k, i, r) for (k, i, r) in sched
-                 if k in (DATA, ADVICE, "ext", "quotient")]
-    lde_src = {DATA: data_lde, ADVICE: adv_lde, "ext": ext_lde,
-               "quotient": q_lde}
-    deep = jnp.zeros((nl, 4), _U32)
-    g_pow = gamma
-    groups = {}
-    for (k, i, r) in committed:
-        groups.setdefault(r, []).append((k, i))
-    for r in sorted(groups):
-        zr = F.emul_fp(z, _U32(pow(w_n, r, F.P)))
-        denom = F.esub(F.ext(pts), jnp.broadcast_to(zr, (nl, 4)))
-        inv_d = F.ebatch_inv(denom)
-        num = jnp.zeros((nl, 4), _U32)
-        for (k, i) in groups[r]:
-            p_lde = lde_src[k][i]
-            diff = F.esub(F.ext(p_lde), jnp.broadcast_to(
-                jnp.asarray(openings[(k, i, r)]), (nl, 4)))
-            num = F.eadd(num, F.emul(jnp.broadcast_to(g_pow, (nl, 4)), diff))
-            g_pow = F.emul(g_pow, gamma)
-        deep = F.eadd(deep, F.emul(num, inv_d))
-    timings["deep"] = time.perf_counter() - t4
+    with phase("deep") as sp:
+        gamma = jnp.asarray(tx.challenge_ext())
+        pts = F.fmul(poly.domain_points(nl), _U32(cfg.shift))   # (NL,)
+        committed = [(k, i, r) for (k, i, r) in sched
+                     if k in (DATA, ADVICE, "ext", "quotient")]
+        lde_src = {DATA: data_lde, ADVICE: adv_lde, "ext": ext_lde,
+                   "quotient": q_lde}
+        deep = jnp.zeros((nl, 4), _U32)
+        g_pow = gamma
+        groups = {}
+        for (k, i, r) in committed:
+            groups.setdefault(r, []).append((k, i))
+        for r in sorted(groups):
+            zr = F.emul_fp(z, _U32(pow(w_n, r, F.P)))
+            denom = F.esub(F.ext(pts), jnp.broadcast_to(zr, (nl, 4)))
+            inv_d = F.ebatch_inv(denom)
+            num = jnp.zeros((nl, 4), _U32)
+            for (k, i) in groups[r]:
+                p_lde = lde_src[k][i]
+                diff = F.esub(F.ext(p_lde), jnp.broadcast_to(
+                    jnp.asarray(openings[(k, i, r)]), (nl, 4)))
+                num = F.eadd(num, F.emul(jnp.broadcast_to(g_pow, (nl, 4)), diff))
+                g_pow = F.emul(g_pow, gamma)
+            deep = F.eadd(deep, F.emul(num, inv_d))
+        sp.sync(deep)
 
     # --- FRI -------------------------------------------------------------------
-    t5 = time.perf_counter()
-    fproof = fri_mod.fri_prove(deep, tx, cfg.fri())
-    timings["fri"] = time.perf_counter() - t5
+    with phase("fri"):
+        fproof = fri_mod.fri_prove(deep, tx, cfg.fri())
 
     # --- query openings ---------------------------------------------------------
-    q_idx = jnp.asarray(fproof.query_indices)
-    idx_all = jnp.concatenate([q_idx, q_idx + nl // 2])
-    tree_openings = {}
-    for name, tree in (("data", data_tree), ("advice", adv_tree),
-                       ("ext", ext_tree), ("quotient", q_tree)):
-        if tree is None:
-            tree_openings[name] = (np.zeros((len(idx_all), 0), np.uint32),
-                                   np.zeros((len(idx_all), 0, 8), np.uint32))
-        else:
-            rows, paths = merkle.open_at(tree, idx_all)
-            tree_openings[name] = (np.asarray(rows), np.asarray(paths))
-    timings["total"] = time.perf_counter() - t0
+    with phase("query_openings"):
+        q_idx = jnp.asarray(fproof.query_indices)
+        idx_all = jnp.concatenate([q_idx, q_idx + nl // 2])
+        tree_openings = {}
+        for name, tree in (("data", data_tree), ("advice", adv_tree),
+                           ("ext", ext_tree), ("quotient", q_tree)):
+            if tree is None:
+                tree_openings[name] = (np.zeros((len(idx_all), 0), np.uint32),
+                                       np.zeros((len(idx_all), 0, 8), np.uint32))
+            else:
+                rows, paths = merkle.open_at(tree, idx_all)
+                tree_openings[name] = (np.asarray(rows), np.asarray(paths))
 
     # strip fixed/instance openings from the transmitted proof (verifier
     # recomputes them); keep data/advice/ext/quotient
     sent = {k: v for k, v in openings.items()
             if k[0] in (DATA, ADVICE, "ext", "quotient")}
     return Proof(data_root, adv_root, ext_root, q_root, sent, fproof,
-                 tree_openings, timings)
+                 tree_openings, phase.timings())

@@ -30,12 +30,11 @@ Challenge broadcasts use ``[:, None, :]`` where the solo code used
 """
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import backend as be
 from . import field as F
 from . import fri as fri_mod
@@ -204,7 +203,7 @@ def prove_batch(keys: pv.Keys, witnesses: list, label: str = "zkgraph",
 
     ``witnesses``: list of ``(advice_np, instance_np, data_np)`` triples,
     all for ``keys.circuit``.  Returns one :class:`~repro.core.prover.Proof`
-    per lane, wire-byte-identical (timings aside) to the solo
+    per lane, wire-byte-identical to the solo
     ``prove(keys, ...)`` of that lane.  ``placement`` (optional,
     :class:`repro.serve.placement.Placement`) shards the lane axis across a
     device mesh; ``None`` keeps everything on the default device.
@@ -222,199 +221,198 @@ def _prove_batch_impl(keys: pv.Keys, witnesses: list, label: str,
     nl = n * B
     lanes = len(witnesses)
     assert lanes >= 1, "prove_batch needs at least one lane"
-    t0 = time.perf_counter()
-    timings = {}
+    phase = obs.Phases("zkg.prove", lanes=lanes)
 
-    adv_list, inst_list, data_list = [], [], []
-    for advice_np, instance_np, data_np in witnesses:
-        if data_np is None:
-            data_np = np.zeros((0, n), np.uint32)
-        pv.auto_multiplicities(circuit, data_np, advice_np, instance_np)
-        adv_list.append(advice_np.astype(np.uint32))
-        inst_list.append(instance_np.astype(np.uint32))
-        data_list.append(data_np.astype(np.uint32))
-    advice = jnp.asarray(np.stack(adv_list))               # (L, n_adv, n)
-    data = jnp.asarray(np.stack(data_list)) if circuit.n_data \
-        else jnp.zeros((lanes, 0, n), _U32)
-    inst = jnp.asarray(np.stack(inst_list)) if circuit.n_instance \
-        else jnp.zeros((lanes, 0, n), _U32)
-    if placement is not None:
-        advice, data, inst = placement.shard_lanes(advice, data, inst)
+    with phase("commit_advice") as sp:
+        adv_list, inst_list, data_list = [], [], []
+        for advice_np, instance_np, data_np in witnesses:
+            if data_np is None:
+                data_np = np.zeros((0, n), np.uint32)
+            pv.auto_multiplicities(circuit, data_np, advice_np, instance_np)
+            adv_list.append(advice_np.astype(np.uint32))
+            inst_list.append(instance_np.astype(np.uint32))
+            data_list.append(data_np.astype(np.uint32))
+        advice = jnp.asarray(np.stack(adv_list))               # (L, n_adv, n)
+        data = jnp.asarray(np.stack(data_list)) if circuit.n_data \
+            else jnp.zeros((lanes, 0, n), _U32)
+        inst = jnp.asarray(np.stack(inst_list)) if circuit.n_instance \
+            else jnp.zeros((lanes, 0, n), _U32)
+        if placement is not None:
+            advice, data, inst = placement.shard_lanes(advice, data, inst)
 
-    btx = BatchedTranscript(label, lanes)
-    btx.absorb_shared(circuit.digest_seed())
-    if circuit.n_instance:
-        inst_tree = merkle.commit_lanes(inst.transpose(0, 2, 1))
-        btx.absorb_digest(np.asarray(inst_tree.roots))
+        btx = BatchedTranscript(label, lanes)
+        btx.absorb_shared(circuit.digest_seed())
+        if circuit.n_instance:
+            inst_tree = merkle.commit_lanes(inst.transpose(0, 2, 1))
+            btx.absorb_digest(np.asarray(inst_tree.roots))
 
-    # --- phase 0: commit the dataset (the declared-DB binding) --------------
-    data_coeffs = poly.intt(data) if circuit.n_data else data
-    data_lde = _lde_lanes(data, B, cfg.shift)
-    data_tree = merkle.commit_lanes(data_lde.transpose(0, 2, 1)) \
-        if circuit.n_data else None
-    data_roots = np.asarray(data_tree.roots) if data_tree \
-        else np.zeros((lanes, 8), np.uint32)
-    btx.absorb_digest(data_roots)
+        # --- phase 0: commit the dataset (the declared-DB binding) --------------
+        data_coeffs = poly.intt(data) if circuit.n_data else data
+        data_lde = _lde_lanes(data, B, cfg.shift)
+        data_tree = merkle.commit_lanes(data_lde.transpose(0, 2, 1)) \
+            if circuit.n_data else None
+        data_roots = np.asarray(data_tree.roots) if data_tree \
+            else np.zeros((lanes, 8), np.uint32)
+        btx.absorb_digest(data_roots)
 
-    # --- phase 1: commit advice -------------------------------------------
-    adv_coeffs = poly.intt(advice) if circuit.n_advice else advice
-    adv_lde = _lde_lanes(advice, B, cfg.shift)
-    adv_tree = merkle.commit_lanes(adv_lde.transpose(0, 2, 1)) \
-        if circuit.n_advice else None
-    adv_roots = np.asarray(adv_tree.roots) if adv_tree \
-        else np.zeros((lanes, 8), np.uint32)
-    btx.absorb_digest(adv_roots)
-    timings["commit_advice"] = time.perf_counter() - t0
+        # --- phase 1: commit advice -------------------------------------------
+        adv_coeffs = poly.intt(advice) if circuit.n_advice else advice
+        adv_lde = _lde_lanes(advice, B, cfg.shift)
+        adv_tree = merkle.commit_lanes(adv_lde.transpose(0, 2, 1)) \
+            if circuit.n_advice else None
+        adv_roots = np.asarray(adv_tree.roots) if adv_tree \
+            else np.zeros((lanes, 8), np.uint32)
+        btx.absorb_digest(adv_roots)
+        sp.sync(data_coeffs, adv_coeffs, data_lde, adv_lde)
 
     alpha = jnp.asarray(btx.challenge_ext())               # (L, 4)
     beta = jnp.asarray(btx.challenge_ext())
 
     # --- phase 2: ext columns ----------------------------------------------
-    t1 = time.perf_counter()
-    fixed_n = jnp.asarray(np.stack(circuit.fixed_cols)
-                          if circuit.fixed_cols
-                          else np.zeros((0, n), np.uint32))
-    fixed_n_lanes = jnp.broadcast_to(fixed_n, (lanes,) + fixed_n.shape)
+    with phase("phase2_ext") as sp:
+        fixed_n = jnp.asarray(np.stack(circuit.fixed_cols)
+                              if circuit.fixed_cols
+                              else np.zeros((0, n), np.uint32))
+        fixed_n_lanes = jnp.broadcast_to(fixed_n, (lanes,) + fixed_n.shape)
 
-    def getter_n(kind, idx, rot):
-        src = {FIXED: fixed_n_lanes, ADVICE: advice, INSTANCE: inst,
-               DATA: data}[kind]
-        return jnp.roll(src[:, idx], -rot, axis=-1)
+        def getter_n(kind, idx, rot):
+            src = {FIXED: fixed_n_lanes, ADVICE: advice, INSTANCE: inst,
+                   DATA: data}[kind]
+            return jnp.roll(src[:, idx], -rot, axis=-1)
 
-    like_n = jnp.zeros((lanes, n), _U32)
-    ext_cols = _build_ext_columns_lanes(circuit, getter_n, like_n, alpha, beta)
-    n_ext = circuit.n_ext
-    ext_base = ext_cols.transpose(0, 1, 3, 2).reshape(lanes, n_ext * 4, n) \
-        if n_ext else jnp.zeros((lanes, 0, n), _U32)
-    ext_coeffs = poly.intt(ext_base) if n_ext else ext_base
-    ext_lde = _lde_lanes(ext_base, B, cfg.shift)
-    ext_tree = merkle.commit_lanes(ext_lde.transpose(0, 2, 1)) \
-        if n_ext else None
-    ext_roots = np.asarray(ext_tree.roots) if ext_tree \
-        else np.zeros((lanes, 8), np.uint32)
-    btx.absorb_digest(ext_roots)
-    timings["phase2_ext"] = time.perf_counter() - t1
+        like_n = jnp.zeros((lanes, n), _U32)
+        ext_cols = _build_ext_columns_lanes(circuit, getter_n, like_n, alpha, beta)
+        n_ext = circuit.n_ext
+        ext_base = ext_cols.transpose(0, 1, 3, 2).reshape(lanes, n_ext * 4, n) \
+            if n_ext else jnp.zeros((lanes, 0, n), _U32)
+        ext_coeffs = poly.intt(ext_base) if n_ext else ext_base
+        ext_lde = _lde_lanes(ext_base, B, cfg.shift)
+        ext_tree = merkle.commit_lanes(ext_lde.transpose(0, 2, 1)) \
+            if n_ext else None
+        ext_roots = np.asarray(ext_tree.roots) if ext_tree \
+            else np.zeros((lanes, 8), np.uint32)
+        btx.absorb_digest(ext_roots)
+        sp.sync(ext_coeffs, ext_lde)
 
     alpha_c = jnp.asarray(btx.challenge_ext())
 
     # --- quotient -----------------------------------------------------------
-    t2 = time.perf_counter()
-    fixed_lde = jnp.broadcast_to(keys.fixed_lde,
-                                 (lanes,) + keys.fixed_lde.shape)
-    inst_lde = _lde_lanes(inst, B, cfg.shift)
+    with phase("quotient") as sp:
+        fixed_lde = jnp.broadcast_to(keys.fixed_lde,
+                                     (lanes,) + keys.fixed_lde.shape)
+        inst_lde = _lde_lanes(inst, B, cfg.shift)
 
-    def getter_lde(kind, idx, rot):
-        src = {FIXED: fixed_lde, ADVICE: adv_lde, INSTANCE: inst_lde,
-               DATA: data_lde}[kind]
-        return jnp.roll(src[:, idx], -B * rot, axis=-1)
+        def getter_lde(kind, idx, rot):
+            src = {FIXED: fixed_lde, ADVICE: adv_lde, INSTANCE: inst_lde,
+                   DATA: data_lde}[kind]
+            return jnp.roll(src[:, idx], -B * rot, axis=-1)
 
-    def ext_getter_lde(col, rot):
-        comps = [jnp.roll(ext_lde[:, col * 4 + c], -B * rot, axis=-1)
-                 for c in range(4)]
-        return jnp.stack(comps, axis=-1)
+        def ext_getter_lde(col, rot):
+            comps = [jnp.roll(ext_lde[:, col * 4 + c], -B * rot, axis=-1)
+                     for c in range(4)]
+            return jnp.stack(comps, axis=-1)
 
-    like_lde = jnp.zeros((lanes, nl), _U32)
-    row0_lde = (getter_lde(FIXED, circuit.fixed_names.index("__row0"), 0)
-                if circuit.gps else like_lde)
-    c_lde = _combine_constraints_lanes(circuit, getter_lde, alpha, beta,
-                                       alpha_c, like_lde, ext_getter_lde,
-                                       row0_lde)
-    # Z_H(x_i): same period-B host sequence as solo (lane-independent)
-    wn = F.root_of_unity(nl)
-    ratio = pow(wn, n, F.P)
-    vals = np.empty(B, np.uint64)
-    acc = pow(cfg.shift, n, F.P)
-    for i in range(B):
-        vals[i] = (acc - 1) % F.P
-        acc = acc * ratio % F.P
-    zh = np.asarray([vals[i % B] for i in range(nl)], np.uint32)
-    zh_inv = F.fbatch_inv(jnp.asarray(zh))
-    q_evals = F.fmul(c_lde, zh_inv[None, :, None])
-    q_coeffs = poly.coset_coeffs(q_evals.transpose(0, 2, 1), cfg.shift)
-    q_segments = q_coeffs.reshape(lanes, 4, B, n) \
-        .transpose(0, 2, 1, 3).reshape(lanes, B * 4, n)
-    q_lde = pv._lde_from_coeffs(q_segments, B, cfg.shift)
-    q_tree = merkle.commit_lanes(q_lde.transpose(0, 2, 1))
-    q_roots = np.asarray(q_tree.roots)
-    btx.absorb_digest(q_roots)
-    timings["quotient"] = time.perf_counter() - t2
+        like_lde = jnp.zeros((lanes, nl), _U32)
+        row0_lde = (getter_lde(FIXED, circuit.fixed_names.index("__row0"), 0)
+                    if circuit.gps else like_lde)
+        c_lde = _combine_constraints_lanes(circuit, getter_lde, alpha, beta,
+                                           alpha_c, like_lde, ext_getter_lde,
+                                           row0_lde)
+        # Z_H(x_i): same period-B host sequence as solo (lane-independent)
+        wn = F.root_of_unity(nl)
+        ratio = pow(wn, n, F.P)
+        vals = np.empty(B, np.uint64)
+        acc = pow(cfg.shift, n, F.P)
+        for i in range(B):
+            vals[i] = (acc - 1) % F.P
+            acc = acc * ratio % F.P
+        zh = np.asarray([vals[i % B] for i in range(nl)], np.uint32)
+        zh_inv = F.fbatch_inv(jnp.asarray(zh))
+        q_evals = F.fmul(c_lde, zh_inv[None, :, None])
+        q_coeffs = poly.coset_coeffs(q_evals.transpose(0, 2, 1), cfg.shift)
+        q_segments = q_coeffs.reshape(lanes, 4, B, n) \
+            .transpose(0, 2, 1, 3).reshape(lanes, B * 4, n)
+        q_lde = pv._lde_from_coeffs(q_segments, B, cfg.shift)
+        q_tree = merkle.commit_lanes(q_lde.transpose(0, 2, 1))
+        q_roots = np.asarray(q_tree.roots)
+        btx.absorb_digest(q_roots)
+        sp.sync(q_segments, q_lde)
 
     # --- OOD openings --------------------------------------------------------
-    t3 = time.perf_counter()
-    z = jnp.asarray(btx.challenge_ext())                   # (L, 4)
-    sched = pv.opening_schedule(circuit, B)
-    fixed_coeffs = jnp.broadcast_to(keys.fixed_coeffs,
-                                    (lanes,) + keys.fixed_coeffs.shape)
-    coeff_src = {FIXED: fixed_coeffs,
-                 INSTANCE: poly.intt(inst) if circuit.n_instance else inst,
-                 DATA: data_coeffs, ADVICE: adv_coeffs, "ext": ext_coeffs,
-                 "quotient": q_segments}
-    w_n = F.root_of_unity(n)
-    openings = {}              # (kind, i, rot) -> (L, 4) np
-    rots = sorted({r for (_, _, r) in sched})
-    for rot in rots:
-        zr = F.emul_fp(z, _U32(pow(w_n, rot, F.P)))
-        for kind in (FIXED, INSTANCE, DATA, ADVICE, "ext", "quotient"):
-            idxs = [i for (k, i, rr) in sched if k == kind and rr == rot]
-            if not idxs:
-                continue
-            # repeat a row up to a multiple of 8: one gather/eval shape
-            rows = idxs + idxs[:1] * ((-len(idxs)) % 8)
-            coeffs = coeff_src[kind][:, jnp.asarray(rows)]
-            vals = np.asarray(_eval_at_ext_lanes(coeffs, zr))  # (L, m, 4)
-            for j, i in enumerate(idxs):
-                openings[(kind, i, rot)] = vals[:, j]
-    for key in sched:
-        btx.absorb(openings[key])
-    timings["ood_openings"] = time.perf_counter() - t3
+    with phase("ood_openings"):
+        z = jnp.asarray(btx.challenge_ext())                   # (L, 4)
+        sched = pv.opening_schedule(circuit, B)
+        fixed_coeffs = jnp.broadcast_to(keys.fixed_coeffs,
+                                        (lanes,) + keys.fixed_coeffs.shape)
+        coeff_src = {FIXED: fixed_coeffs,
+                     INSTANCE: poly.intt(inst) if circuit.n_instance else inst,
+                     DATA: data_coeffs, ADVICE: adv_coeffs, "ext": ext_coeffs,
+                     "quotient": q_segments}
+        w_n = F.root_of_unity(n)
+        openings = {}              # (kind, i, rot) -> (L, 4) np
+        rots = sorted({r for (_, _, r) in sched})
+        for rot in rots:
+            zr = F.emul_fp(z, _U32(pow(w_n, rot, F.P)))
+            for kind in (FIXED, INSTANCE, DATA, ADVICE, "ext", "quotient"):
+                idxs = [i for (k, i, rr) in sched if k == kind and rr == rot]
+                if not idxs:
+                    continue
+                # repeat a row up to a multiple of 8: one gather/eval shape
+                rows = idxs + idxs[:1] * ((-len(idxs)) % 8)
+                coeffs = coeff_src[kind][:, jnp.asarray(rows)]
+                vals = np.asarray(_eval_at_ext_lanes(coeffs, zr))  # (L, m, 4)
+                for j, i in enumerate(idxs):
+                    openings[(kind, i, rot)] = vals[:, j]
+        for key in sched:
+            btx.absorb(openings[key])
 
     # --- DEEP composition -----------------------------------------------------
-    t4 = time.perf_counter()
-    gamma = jnp.asarray(btx.challenge_ext())
-    pts_ext = F.ext(F.fmul(poly.domain_points(nl), _U32(cfg.shift)))  # (nl,4)
-    committed = [(k, i, r) for (k, i, r) in sched
-                 if k in (DATA, ADVICE, "ext", "quotient")]
-    lde_src = {DATA: data_lde, ADVICE: adv_lde, "ext": ext_lde,
-               "quotient": q_lde}
-    deep = jnp.zeros((lanes, nl, 4), _U32)
-    g_pow = gamma
-    groups = {}
-    for (k, i, r) in committed:
-        groups.setdefault(r, []).append((k, i))
-    for r in sorted(groups):
-        zr = F.emul_fp(z, _U32(pow(w_n, r, F.P)))
-        denom = F.esub(pts_ext[None], zr[:, None, :])
-        inv_d = F.ebatch_inv(denom)
-        num = jnp.zeros((lanes, nl, 4), _U32)
-        for (k, i) in groups[r]:
-            p_lde = lde_src[k][:, i]                       # (L, nl)
-            diff = F.esub(F.ext(p_lde),
-                          jnp.asarray(openings[(k, i, r)])[:, None, :])
-            num = F.eadd(num, F.emul(g_pow[:, None, :], diff))
-            g_pow = F.emul(g_pow, gamma)
-        deep = F.eadd(deep, F.emul(num, inv_d))
-    timings["deep"] = time.perf_counter() - t4
+    with phase("deep") as sp:
+        gamma = jnp.asarray(btx.challenge_ext())
+        pts_ext = F.ext(F.fmul(poly.domain_points(nl), _U32(cfg.shift)))  # (nl,4)
+        committed = [(k, i, r) for (k, i, r) in sched
+                     if k in (DATA, ADVICE, "ext", "quotient")]
+        lde_src = {DATA: data_lde, ADVICE: adv_lde, "ext": ext_lde,
+                   "quotient": q_lde}
+        deep = jnp.zeros((lanes, nl, 4), _U32)
+        g_pow = gamma
+        groups = {}
+        for (k, i, r) in committed:
+            groups.setdefault(r, []).append((k, i))
+        for r in sorted(groups):
+            zr = F.emul_fp(z, _U32(pow(w_n, r, F.P)))
+            denom = F.esub(pts_ext[None], zr[:, None, :])
+            inv_d = F.ebatch_inv(denom)
+            num = jnp.zeros((lanes, nl, 4), _U32)
+            for (k, i) in groups[r]:
+                p_lde = lde_src[k][:, i]                       # (L, nl)
+                diff = F.esub(F.ext(p_lde),
+                              jnp.asarray(openings[(k, i, r)])[:, None, :])
+                num = F.eadd(num, F.emul(g_pow[:, None, :], diff))
+                g_pow = F.emul(g_pow, gamma)
+            deep = F.eadd(deep, F.emul(num, inv_d))
+        sp.sync(deep)
 
     # --- FRI -------------------------------------------------------------------
-    t5 = time.perf_counter()
-    fproofs = fri_mod.fri_prove_lanes(deep, btx, cfg.fri())
-    timings["fri"] = time.perf_counter() - t5
+    with phase("fri"):
+        fproofs = fri_mod.fri_prove_lanes(deep, btx, cfg.fri())
 
     # --- query openings ---------------------------------------------------------
-    q_idx = jnp.asarray(np.stack([fp.query_indices for fp in fproofs]))
-    idx_all = jnp.concatenate([q_idx, q_idx + nl // 2], axis=1)
-    tree_rows = {}             # name -> (rows (L,k,w), paths (L,k,d,8)) np
-    n_open = idx_all.shape[1]
-    for name, tree in (("data", data_tree), ("advice", adv_tree),
-                       ("ext", ext_tree), ("quotient", q_tree)):
-        if tree is None:
-            tree_rows[name] = (
-                np.zeros((lanes, n_open, 0), np.uint32),
-                np.zeros((lanes, n_open, 0, 8), np.uint32))
-        else:
-            rows, paths = merkle.open_lanes(tree, idx_all)
-            tree_rows[name] = (np.asarray(rows), np.asarray(paths))
-    timings["total"] = time.perf_counter() - t0
+    with phase("query_openings"):
+        q_idx = jnp.asarray(np.stack([fp.query_indices for fp in fproofs]))
+        idx_all = jnp.concatenate([q_idx, q_idx + nl // 2], axis=1)
+        tree_rows = {}             # name -> (rows (L,k,w), paths (L,k,d,8)) np
+        n_open = idx_all.shape[1]
+        for name, tree in (("data", data_tree), ("advice", adv_tree),
+                           ("ext", ext_tree), ("quotient", q_tree)):
+            if tree is None:
+                tree_rows[name] = (
+                    np.zeros((lanes, n_open, 0), np.uint32),
+                    np.zeros((lanes, n_open, 0, 8), np.uint32))
+            else:
+                rows, paths = merkle.open_lanes(tree, idx_all)
+                tree_rows[name] = (np.asarray(rows), np.asarray(paths))
+    timings = phase.timings()
 
     # --- per-lane Proof assembly (same key orders as solo) ---------------------
     proofs = []

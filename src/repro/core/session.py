@@ -60,6 +60,7 @@ from dataclasses import dataclass, field as dc_field
 
 import numpy as np
 
+from .. import obs
 from . import backend as be
 from . import commit, ir, wire
 from . import prover as pv
@@ -166,7 +167,8 @@ class KeygenCache:
             wait_on.wait()
             # leader finished (or failed): re-check the cache / re-elect
         try:
-            keys = pv.keygen(op.circuit, cfg)
+            with obs.span("zkg.keygen", rows=op.circuit.n_rows):
+                keys = pv.keygen(op.circuit, cfg)
         except BaseException:
             with self._lock:
                 self._inflight.pop(key, None)
@@ -488,11 +490,12 @@ class ZKGraphSession:
         """Decode + verify a serialized bundle; malformed bytes (including
         legacy pickle and version-mismatched encodings) are simply invalid —
         ``False``, never a crash, never code execution."""
-        try:
-            bundle = ProofBundle.from_bytes(raw)
-        except WireFormatError:
-            return False
-        return self.verify(bundle, commitments)
+        with obs.span("zkg.verify"):
+            try:
+                bundle = ProofBundle.from_bytes(raw)
+            except WireFormatError:
+                return False
+            return self.verify(bundle, commitments)
 
     def verify(self, bundle: ProofBundle,
                commitments: CommitmentManifest = None) -> bool:

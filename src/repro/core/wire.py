@@ -33,7 +33,7 @@ test vectors is ``docs/protocol.md``)::
                  S nsteps:u32 step* R result:value
     step      := K kind:str H shape:value D desc:str I instance:arr F proof
     proof     := 4 roots:arr(8,) OPEN openings TREE tree_openings
-                 FRI friproof T timings:value
+                 FRI friproof
     friproof  := roots:[arr(8,)] final:arr(n,4) qidx:arr(i64)
                  openings:[(rows:arr, paths:arr)]
     manifest  := V mver:u32 N n_nodes:i64 E edge_counts T tables R roots
@@ -53,9 +53,10 @@ import struct
 import numpy as np
 
 MAGIC = b"ZKGB"
-WIRE_VERSION = 3     # v3: gossip envelopes carry Ed25519 detached
-                     # signatures (kind 9); the v2 MAC-era envelope
-                     # (kind 8) is retired and rejected by name
+WIRE_VERSION = 4     # v4: a proof ends at its FRI proof (the v3 timings
+                     # field 0x24 is gone); v3: gossip envelopes carry
+                     # Ed25519 detached signatures (kind 9), and the v2
+                     # MAC-era envelope (kind 8) is rejected by name
 
 # payload kinds (a message's top-level type)
 KIND_BUNDLE = 1
@@ -101,8 +102,8 @@ _F_QUERY, _F_PARAMS, _F_CFG, _F_STEPS, _F_RESULT, _F_DIGEST = \
     0x01, 0x02, 0x03, 0x04, 0x05, 0x06
 _F_KIND, _F_SHAPE, _F_DESC, _F_INSTANCE, _F_PROOF = \
     0x10, 0x11, 0x12, 0x13, 0x14
-_F_ROOTS, _F_OPENINGS, _F_TREES, _F_FRI, _F_TIMINGS = \
-    0x20, 0x21, 0x22, 0x23, 0x24
+_F_ROOTS, _F_OPENINGS, _F_TREES, _F_FRI = 0x20, 0x21, 0x22, 0x23
+# 0x24 was the v3 proof timings (host telemetry); retired, never reused
 _F_FRI_ROOTS, _F_FRI_FINAL, _F_FRI_QIDX, _F_FRI_OPENS = \
     0x30, 0x31, 0x32, 0x33
 _F_M_VERSION, _F_M_NNODES, _F_M_EDGES, _F_M_TABLES, _F_M_ROOTS = \
@@ -488,8 +489,6 @@ def _proof_to_wire(e: _Enc, p):
         e.array(paths, dtype=np.uint32, ndim=3)
     e.u8(_F_FRI)
     _fri_to_wire(e, p.fri_proof)
-    e.u8(_F_TIMINGS)
-    e.value({str(k): float(v) for k, v in p.timings.items()})
 
 
 def _proof_from_wire(d: _Dec):
@@ -529,14 +528,8 @@ def _proof_from_wire(d: _Dec):
         trees[name] = (rows, paths)
     d.tag(_F_FRI, "proof.fri_proof")
     fri_proof = _fri_from_wire(d)
-    d.tag(_F_TIMINGS, "proof.timings")
-    timings = d.value()
-    if not isinstance(timings, dict) or not all(
-            isinstance(k, str) and isinstance(v, float)
-            for k, v in timings.items()):
-        raise WireFormatError("proof timings must be a {str: float} dict")
     return Proof(roots[0], roots[1], roots[2], roots[3], openings, fri_proof,
-                 trees, timings)
+                 trees)
 
 
 # ---------------------------------------------------------------------------

@@ -8,15 +8,20 @@ import jax
 from jax.experimental import pallas as pl
 
 
-def pallas_call(kernel, **kwargs):
-    """``pl.pallas_call`` whose kernel and index maps trace with 64-bit
-    types off.
+def pallas_call(kernel, *, name: str, **kwargs):
+    """``pl.pallas_call`` under ``name``, whose kernel and index maps trace
+    with 64-bit types off.
+
+    ``name`` is the kernel's name in the compiled program, so that a device
+    trace names the kernel, not only the jitted function around it.
 
     ``repro.core.field`` turns ``jax_enable_x64`` on for the whole process.
     Under it the integer literals in BlockSpec index maps, loop bounds and
     roll amounts trace as ``i64``, which the TPU compiler refuses.  The
     kernels compute on ``uint32`` only, so no value changes."""
-    call = pl.pallas_call(kernel, **kwargs)
+    if not name:
+        raise ValueError("every Pallas kernel needs a name")
+    call = pl.pallas_call(kernel, name=name, **kwargs)
 
     def run(*args):
         with jax.enable_x64(False):

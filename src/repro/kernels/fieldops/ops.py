@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import pallas_call
 from . import fieldops as K
 
 _U32 = jnp.uint32
@@ -44,8 +45,9 @@ def mulmod(a: jnp.ndarray, b: jnp.ndarray, interpret: bool = True):
     flat_a = _pad_flat(a.reshape(-1).astype(_U32))
     flat_b = _pad_flat(b.reshape(-1).astype(_U32))
     block = _pick_block(flat_a.shape[0])
-    out = pl.pallas_call(
+    out = pallas_call(
         K._mulmod_kernel,
+        name="fieldops_mulmod",
         grid=(flat_a.shape[0] // block,),
         in_specs=[pl.BlockSpec((block,), lambda i: (i,))] * 2,
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
@@ -63,8 +65,9 @@ def fused_mul_add(a, b, c, interpret: bool = True):
     flat_a, flat_b, flat_c = (_pad_flat(x.reshape(-1).astype(_U32))
                               for x in (a, b, c))
     block = _pick_block(flat_a.shape[0])
-    out = pl.pallas_call(
+    out = pallas_call(
         K._fma_kernel,
+        name="fieldops_fma",
         grid=(flat_a.shape[0] // block,),
         in_specs=[pl.BlockSpec((block,), lambda i: (i,))] * 3,
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),

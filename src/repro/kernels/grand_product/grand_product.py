@@ -133,6 +133,7 @@ def exclusive_scan(planes: jnp.ndarray, block_rows: int, ext: bool,
     block = (k, block_rows, LANES)
     return pallas_call(
         functools.partial(_scan_kernel, mul=_emul_limb if ext else _mul_limb),
+        name="grand_product_ext" if ext else "grand_product",
         grid=(rows // block_rows,),
         in_specs=[pl.BlockSpec(block, lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec(block, lambda i: (0, i, 0)),

@@ -54,6 +54,7 @@ def local_stages(x: jnp.ndarray, tw_lanes: jnp.ndarray, row_tile: int,
     rows, w = x.shape
     return pallas_call(
         _local_kernel,
+        name="ntt_local",
         grid=(rows // row_tile,),
         in_specs=[pl.BlockSpec((row_tile, w), lambda i: (i, 0)),
                   pl.BlockSpec(tw_lanes.shape, lambda i: (0, 0, 0))],
@@ -84,6 +85,7 @@ def stage(x: jnp.ndarray, twiddles: jnp.ndarray, m: int, batch_tile: int,
     half = (batch_tile, sq, sq, tr, LANES)
     out = pallas_call(
         _stage_kernel,
+        name="ntt_stage",
         grid=(b // batch_tile, g, r // tr, 2),
         in_specs=[pl.BlockSpec(half, lambda i, j, k, p: (i, j, 0, k, 0)),
                   pl.BlockSpec(half, lambda i, j, k, p: (i, j, 1, k, 0)),

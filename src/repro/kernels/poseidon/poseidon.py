@@ -67,6 +67,7 @@ def permute(states_t: jnp.ndarray, block: int,
     mds3 = jnp.asarray(mds[:, :, None])        # row i of mds as a column
     return pallas_call(
         _permute_kernel,
+        name="poseidon_permute",
         grid=(n // block,),
         in_specs=[
             pl.BlockSpec((H.WIDTH, block), lambda i: (0, i)),

@@ -17,6 +17,8 @@ import time
 from collections import OrderedDict
 from dataclasses import dataclass, field as dc_field
 
+from .. import obs
+
 
 @dataclass
 class StepSlot:
@@ -25,6 +27,7 @@ class StepSlot:
     pos: int                # index into the query's plan-step order
     step: object            # ir.Step (witness already built)
     enqueued: float = dc_field(default_factory=time.monotonic)
+    enqueued_ns: int = dc_field(default_factory=obs.now)   # zkg.queue start
 
 
 @dataclass

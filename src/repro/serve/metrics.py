@@ -73,6 +73,7 @@ class ServiceMetrics:
         counters:        submitted / completed / failed / batches /
                          lanes / pad_lanes
         phase_us:        per prover phase -> {count, mean, p50, p95, max}
+        witness_us:      per-query witness stage time   (same stats dict)
         queue_wait_us:   submit -> batch-flush wait     (same stats dict)
         prove_us:        per-batch prove wall time      (same stats dict)
         batch_occupancy: real lanes per flushed batch   (same stats dict)
@@ -84,6 +85,7 @@ class ServiceMetrics:
         self._counters = dict(submitted=0, completed=0, failed=0, batches=0,
                               lanes=0, pad_lanes=0)
         self.phase_us = {p: Histogram() for p in PHASES}
+        self.witness_us = Histogram()
         self.queue_wait_us = Histogram()
         self.prove_us = Histogram()
         self.batch_occupancy = Histogram()
@@ -106,6 +108,7 @@ class ServiceMetrics:
         out = dict(
             counters=self.counters(),
             phase_us={p: h.snapshot() for p, h in self.phase_us.items()},
+            witness_us=self.witness_us.snapshot(),
             queue_wait_us=self.queue_wait_us.snapshot(),
             prove_us=self.prove_us.snapshot(),
             batch_occupancy=self.batch_occupancy.snapshot(),

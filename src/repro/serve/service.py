@@ -2,9 +2,9 @@
 
 ``submit(qname, params)`` returns a ``concurrent.futures.Future`` resolving
 to the same :class:`~repro.core.session.ProofBundle` a direct
-``session.prove`` call would produce — wire-byte-identical (timings aside),
-which is what lets one service answer many mutually-distrustful clients:
-batching is invisible in the artifact.
+``session.prove`` call would produce — wire-byte-identical, which is what
+lets one service answer many mutually-distrustful clients: batching is
+invisible in the artifact.
 
 Dataflow (docs/serving.md has the picture)::
 
@@ -29,10 +29,10 @@ keeps serving.
 from __future__ import annotations
 
 import threading
-import time
 from concurrent.futures import Future
 from dataclasses import dataclass, field as dc_field
 
+from .. import obs
 from ..core import backend as be
 from ..core.session import ProofBundle, ZKGraphSession
 from .batching import BatchReady, ShapeBatcher, StepSlot
@@ -50,7 +50,9 @@ class _Ticket:
     qname: str
     params: dict
     future: Future
-    submitted: float = dc_field(default_factory=time.monotonic)
+    request: int = dc_field(default_factory=obs.new_id)  # zkg.request id
+    submitted_ns: int = dc_field(default_factory=obs.now)
+    witness_span: int = None    # zkg.witness id, parent of the queue spans
     run: object = None          # ir.QueryRun once the witness stage ran
     results: list = None        # per-step StepProof slots (plan order)
     remaining: int = 0
@@ -147,7 +149,12 @@ class ProofService:
     # -- witness stage -------------------------------------------------------
     def _handle_ticket(self, ticket: _Ticket):
         with be.use(self._backend):
-            run = self.session.run_query(ticket.qname, ticket.params)
+            with obs.span("zkg.witness", parent=ticket.request,
+                          request=ticket.request) as sp:
+                run = self.session.run_query(ticket.qname, ticket.params)
+                sp.set(steps=len(run.steps))
+            self.metrics.witness_us.observe(sp.seconds * 1e6)
+            ticket.witness_span = sp.id
             ticket.run = run
             ticket.results = [None] * len(run.steps)
             ticket.remaining = len(run.steps)
@@ -183,17 +190,20 @@ class ProofService:
         live = [s for s in ready.slots if not s.ticket.failed]
         if not live:
             return
-        now = time.monotonic()
+        now = obs.now()
         for s in live:
-            self.metrics.queue_wait_us.observe((now - s.enqueued) * 1e6)
+            obs.record("zkg.queue", s.enqueued_ns, now,
+                       parent=s.ticket.witness_span, request=s.ticket.request)
+            self.metrics.queue_wait_us.observe((now - s.enqueued_ns) / 1e3)
         steps = [s.step for s in live]
         pad = self._lane_count(len(steps)) - len(steps)
-        t0 = time.perf_counter()
-        with be.use(self._backend):
+        with obs.span("zkg.prove_batch", lanes=len(steps), pad_lanes=pad,
+                      requests=[s.ticket.request for s in live]) as sp, \
+                be.use(self._backend):
             # pad lanes replicate the last witness; their proofs are
             # discarded (bit-identity makes them redundant, not wrong)
             step_proofs = self.session.prove_steps(steps + [steps[-1]] * pad)
-        self.metrics.prove_us.observe((time.perf_counter() - t0) * 1e6)
+        self.metrics.prove_us.observe(sp.seconds * 1e6)
         self.metrics.inc("batches")
         self.metrics.inc("lanes", len(steps))
         self.metrics.inc("pad_lanes", pad)
@@ -223,6 +233,9 @@ class ProofService:
                              list(ticket.results or []), ticket.run.result,
                              self.session.cfg, self._manifest_digest)
         self.metrics.inc("completed")
+        obs.record("zkg.request", ticket.submitted_ns, obs.now(),
+                   span_id=ticket.request, request=ticket.request,
+                   query=ticket.qname)
         ticket.future.set_result(bundle)
 
     def _fail(self, ticket: _Ticket, exc: BaseException):
@@ -231,4 +244,7 @@ class ProofService:
                 return
             ticket.failed = True
         self.metrics.inc("failed")
+        obs.record("zkg.request", ticket.submitted_ns, obs.now(),
+                   span_id=ticket.request, request=ticket.request,
+                   query=ticket.qname, failed=1)
         ticket.future.set_exception(exc)

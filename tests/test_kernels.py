@@ -4,6 +4,9 @@ values, and the uint32 16-bit-limb mulmod path vs the uint64 oracle.
 hypothesis is optional: only the property-based test skips without it —
 the rest of the kernel suite must run everywhere (CI runs this module
 under ``ZKGRAPH_BACKEND=pallas-interpret`` to catch kernel drift)."""
+import ast
+from pathlib import Path
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -151,3 +154,54 @@ def test_poseidon_kernel_zero_state():
     want = np.asarray(pos_ref.permute_ref(x))
     np.testing.assert_array_equal(got, want)
     assert not np.array_equal(got[0], np.zeros(16))  # permutation moves zero
+
+
+# ---------------------------------------------------------------------------
+# kernel names: every Pallas call is named, so a device trace names it
+# ---------------------------------------------------------------------------
+KERNEL_NAMES = {"ntt_local", "ntt_stage", "poseidon_permute",
+                "grand_product", "grand_product_ext", "fieldops_mulmod",
+                "fieldops_fma"}
+
+
+def _literal_names(node) -> list:
+    if isinstance(node, ast.Constant):
+        return [node.value]
+    if isinstance(node, ast.IfExp):
+        return _literal_names(node.body) + _literal_names(node.orelse)
+    raise AssertionError(f"kernel name is not a literal: {ast.dump(node)}")
+
+
+def test_every_pallas_call_site_passes_a_name():
+    """Each kernel goes through ``repro.kernels.pallas_call`` with a
+    literal ``name=``; ``pl.pallas_call`` appears only inside that
+    wrapper."""
+    import repro.kernels
+    root = Path(repro.kernels.__file__).parent
+    names = []
+    for path in sorted(root.rglob("*.py")):
+        rel = path.relative_to(root)
+        for call in ast.walk(ast.parse(path.read_text())):
+            if not isinstance(call, ast.Call):
+                continue
+            direct = isinstance(call.func, ast.Attribute) and \
+                call.func.attr == "pallas_call"
+            wrapped = isinstance(call.func, ast.Name) and \
+                call.func.id == "pallas_call"
+            if direct:
+                assert str(rel) == "__init__.py", \
+                    f"{rel}:{call.lineno}: pl.pallas_call outside the wrapper"
+            if wrapped:
+                kw = {k.arg: k.value for k in call.keywords}
+                assert "name" in kw, \
+                    f"{rel}:{call.lineno}: pallas_call without a name"
+                names.extend(_literal_names(kw["name"]))
+    assert set(names) == KERNEL_NAMES
+
+
+def test_pallas_call_requires_a_name():
+    from repro import kernels
+    with pytest.raises(TypeError):
+        kernels.pallas_call(lambda x_ref, o_ref: None)
+    with pytest.raises(ValueError):
+        kernels.pallas_call(lambda x_ref, o_ref: None, name="")

@@ -193,14 +193,14 @@ def test_service_metrics_schema(db, owner, tiny_cfg):
         svc.submit("IS5", dict(message=(1 << 20) + 2)).result(timeout=600)
         stats = svc.stats()
     # the documented schema (docs/serving.md) — exact top-level keys
-    assert set(stats) == {"counters", "phase_us", "queue_wait_us",
-                          "prove_us", "batch_occupancy", "keygen_cache",
-                          "depths"}
+    assert set(stats) == {"counters", "phase_us", "witness_us",
+                          "queue_wait_us", "prove_us", "batch_occupancy",
+                          "keygen_cache", "depths"}
     assert set(stats["counters"]) == {"submitted", "completed", "failed",
                                       "batches", "lanes", "pad_lanes"}
     assert {"fri", "total"} <= set(stats["phase_us"])
-    for stat in (stats["phase_us"]["total"], stats["queue_wait_us"],
-                 stats["batch_occupancy"]):
+    for stat in (stats["phase_us"]["total"], stats["witness_us"],
+                 stats["queue_wait_us"], stats["batch_occupancy"]):
         assert set(stat) == {"count", "mean", "p50", "p95", "max"}
     assert set(stats["keygen_cache"]) == {"hits", "misses", "waits",
                                           "entries"}

@@ -218,7 +218,7 @@ def test_wire_constants_pinned():
     """The spec constants in docs/protocol.md §1 are written against these
     values; bump the doc and regenerate vectors when changing them."""
     assert wire.MAGIC == b"ZKGB"
-    assert wire.WIRE_VERSION == 3
+    assert wire.WIRE_VERSION == 4
     assert (wire.KIND_BUNDLE, wire.KIND_PROOF, wire.KIND_FRI,
             wire.KIND_MANIFEST, wire.KIND_CHECKPOINT, wire.KIND_INCLUSION,
             wire.KIND_CONSISTENCY, wire.KIND_GOSSIP) == (1, 2, 3, 4, 5, 6,

@@ -98,6 +98,29 @@ def test_version_mismatch_rejected_and_verify_bytes_false(raw, verifier):
     assert verifier.verify_bytes(raw) is True
 
 
+def test_v3_bytes_fail_closed(raw, verifier):
+    """Wire v4 dropped the proof's timings field; a v3 message is refused
+    on its version, before any field is read."""
+    v3 = raw[:4] + struct.pack("<H", 3) + raw[6:]
+    with pytest.raises(WireFormatError, match="unsupported wire version 3"):
+        ProofBundle.from_bytes(v3)
+    assert verifier.verify_bytes(v3) is False
+
+
+def test_proofs_carry_no_timings(bundle):
+    """Phase timings are in-memory telemetry: they do not reach the wire,
+    and a decoded proof has none."""
+    import dataclasses
+    from repro.core.prover import Proof
+    proof = bundle.steps[0].proof
+    timed = dataclasses.replace(proof, timings={"fri": 1.5, "total": 9.0})
+    bare = dataclasses.replace(proof, timings={})
+    assert timed.to_bytes() == bare.to_bytes() == proof.to_bytes()
+    assert Proof.from_bytes(timed.to_bytes()).timings == {}
+    assert all(s.proof.timings == {}
+               for s in ProofBundle.from_bytes(bundle.to_bytes()).steps)
+
+
 def test_payload_kind_confusion_rejected(bundle, raw):
     proof_bytes = bundle.steps[0].proof.to_bytes()
     with pytest.raises(WireFormatError):
