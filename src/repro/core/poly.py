@@ -126,20 +126,46 @@ def coset_coeffs(evals: jnp.ndarray, shift: int) -> jnp.ndarray:
     return F.fmul(coeffs, jnp.asarray(powers.astype(np.uint32)))
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def ext_powers(z: jnp.ndarray, n: int) -> jnp.ndarray:
+    """The powers z^0 .. z^(n-1) of Fp4 points: (..., 4) -> (..., n, 4).
+
+    ``n`` is a power of two.  Baby steps and giant steps: with
+    ``s = 2^ceil(log2(n)/2)`` and ``g = n/s``, one scan gives ``z^i`` for
+    i < s (its last carry is ``z^s``), a second gives ``z^(s*j)`` for j < g,
+    and one broadcast product of the two fills row ``j*s + i`` with
+    ``z^(j*s+i)``.  On the TPU every scan step is one serial ``while``
+    iteration, so an n-step scan of single products runs n of them; this
+    runs s + g (512 at n = 2^16).  A doubling table would need fewer still,
+    but traces log2(n) products, and TPU compile time grows with traced
+    products (``field._square_and_multiply``)."""
+    s = 1 << (((n - 1).bit_length() + 1) // 2)
+    g = n // s
+
+    def step(mult):
+        return lambda carry, _: (F.emul(carry, mult), carry)
+
+    one = jnp.broadcast_to(jnp.asarray(F.EXT_ONE), z.shape).astype(_U32)
+    z_s, small = jax.lax.scan(step(z), one, None, length=s)       # (s, ..., 4)
+    _, giant = jax.lax.scan(step(z_s), one, None, length=g)       # (g, ..., 4)
+    small = jnp.moveaxis(small, 0, -2)                            # (..., s, 4)
+    giant = jnp.moveaxis(giant, 0, -2)                            # (..., g, 4)
+    table = F.emul(giant[..., :, None, :], small[..., None, :, :])
+    return table.reshape(z.shape[:-1] + (n, 4))
+
+
 @jax.jit
 def eval_at_ext(coeffs: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
-    """Horner-evaluate an Fp-coefficient polynomial at an Fp4 point ``z``.
+    """Evaluate an Fp-coefficient polynomial at an Fp4 point ``z``.
 
-    coeffs: (..., n) Fp; z: (4,) Fp4. Returns (..., 4).
-    Uses a power-table + dot to stay vectorized: sum_i c_i * z^i.
+    coeffs: (..., n) Fp; z: (4,) Fp4. Returns (..., 4): sum_i c_i * z^i,
+    as one product with the power table of :func:`ext_powers` and a
+    modular sum, vectorized over the rows.  The table comes from two scans
+    of about sqrt(n) steps (baby steps z^i, giant steps z^(s*j)) and one
+    broadcast product: each scan step is a serial ``while`` iteration on
+    the TPU, so a single scan over all n powers would run n of them.
     """
-    n = coeffs.shape[-1]
-    # z powers: (n, 4)
-    def step(carry, _):
-        nxt = F.emul(carry, z)
-        return nxt, carry
-    one = jnp.asarray(F.EXT_ONE)
-    _, zpows = jax.lax.scan(step, one, None, length=n)
+    zpows = ext_powers(z, coeffs.shape[-1])                      # (n, 4)
     # sum_i c_i * zpows[i]: (..., n, 1) * (n, 4) -> mod-P dot
     prod = F.fmul(coeffs[..., None].astype(_U32), zpows)      # (..., n, 4)
     # modular sum along axis -2 (values < P; sum in uint64 then reduce)

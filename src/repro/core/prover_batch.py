@@ -178,17 +178,11 @@ def _combine_constraints_lanes(circuit, base_getter, alpha, beta, alpha_c,
 
 @jax.jit
 def _eval_at_ext_lanes(coeffs: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
-    """Horner-evaluate (L, m, n) Fp coefficients at per-lane Fp4 ``z``
-    (L, 4) -> (L, m, 4); mirrors poly.eval_at_ext per lane (jit like it —
-    the inner scan must not re-trace on each of the rot x kind calls)."""
-    n = coeffs.shape[-1]
-
-    def step(carry, _):
-        return F.emul(carry, z), carry
-
-    one = jnp.broadcast_to(jnp.asarray(F.EXT_ONE), z.shape).astype(_U32)
-    _, zpows = jax.lax.scan(step, one, None, length=n)     # (n, L, 4)
-    zpows = jnp.moveaxis(zpows, 0, 1)                      # (L, n, 4)
+    """Evaluate (L, m, n) Fp coefficients at per-lane Fp4 ``z``
+    (L, 4) -> (L, m, 4): poly.eval_at_ext per lane, over one (L, n, 4)
+    table from poly.ext_powers (jit like it — the table's scans must not
+    re-trace on each of the rot x kind calls)."""
+    zpows = poly.ext_powers(z, coeffs.shape[-1])           # (L, n, 4)
     prod = F.fmul(coeffs[..., None].astype(_U32), zpows[:, None, :, :])
     s = F.mod_p(jnp.sum(prod.astype(jnp.uint64), axis=-2))
     return s.astype(_U32)
