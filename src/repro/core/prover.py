@@ -337,7 +337,7 @@ def _prove_impl(keys: Keys, advice_np: np.ndarray, instance_np: np.ndarray,
     circuit, cfg = keys.circuit, keys.cfg
     n, B = circuit.n_rows, cfg.blowup
     nl = n * B
-    phase = obs.Phases("zkg.prove", lanes=1)
+    phase = obs.Phases("zkg.prove", lanes=1, circuit=circuit.name)
 
     with phase("commit_advice") as sp:
         if data_np is None:

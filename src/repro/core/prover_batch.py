@@ -215,7 +215,7 @@ def _prove_batch_impl(keys: pv.Keys, witnesses: list, label: str,
     nl = n * B
     lanes = len(witnesses)
     assert lanes >= 1, "prove_batch needs at least one lane"
-    phase = obs.Phases("zkg.prove", lanes=lanes)
+    phase = obs.Phases("zkg.prove", lanes=lanes, circuit=circuit.name)
 
     with phase("commit_advice") as sp:
         adv_list, inst_list, data_list = [], [], []

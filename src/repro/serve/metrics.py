@@ -76,6 +76,9 @@ class ServiceMetrics:
         witness_us:      per-query witness stage time   (same stats dict)
         queue_wait_us:   submit -> batch-flush wait     (same stats dict)
         prove_us:        per-batch prove wall time      (same stats dict)
+        prove_us_by_circuit: circuit name -> per-batch prove wall time of
+                         that circuit's batches (same stats dict; an
+                         entry appears with the circuit's first batch)
         batch_occupancy: real lanes per flushed batch   (same stats dict)
         keygen_cache:    {hits, misses, waits, entries} (KeygenCache.stats)
     """
@@ -88,6 +91,7 @@ class ServiceMetrics:
         self.witness_us = Histogram()
         self.queue_wait_us = Histogram()
         self.prove_us = Histogram()
+        self.prove_us_by_circuit = {}
         self.batch_occupancy = Histogram()
 
     def inc(self, name: str, by: int = 1):
@@ -100,17 +104,29 @@ class ServiceMetrics:
             if phase in timings:
                 self.phase_us[phase].observe(timings[phase] * 1e6)
 
+    def observe_prove(self, circuit: str, us: float):
+        """Record one batch's prove wall time, pooled and by circuit."""
+        self.prove_us.observe(us)
+        with self._lock:
+            h = self.prove_us_by_circuit.get(circuit)
+            if h is None:
+                h = self.prove_us_by_circuit[circuit] = Histogram()
+        h.observe(us)
+
     def counters(self) -> dict:
         with self._lock:
             return dict(self._counters)
 
     def snapshot(self, cache_stats: dict = None) -> dict:
+        with self._lock:
+            by_circuit = sorted(self.prove_us_by_circuit.items())
         out = dict(
             counters=self.counters(),
             phase_us={p: h.snapshot() for p, h in self.phase_us.items()},
             witness_us=self.witness_us.snapshot(),
             queue_wait_us=self.queue_wait_us.snapshot(),
             prove_us=self.prove_us.snapshot(),
+            prove_us_by_circuit={c: h.snapshot() for c, h in by_circuit},
             batch_occupancy=self.batch_occupancy.snapshot(),
         )
         if cache_stats is not None:

@@ -197,13 +197,15 @@ class ProofService:
             self.metrics.queue_wait_us.observe((now - s.enqueued_ns) / 1e3)
         steps = [s.step for s in live]
         pad = self._lane_count(len(steps)) - len(steps)
+        circuit = steps[0].op.circuit
         with obs.span("zkg.prove_batch", lanes=len(steps), pad_lanes=pad,
+                      circuit=circuit.name, rows=circuit.n_rows,
                       requests=[s.ticket.request for s in live]) as sp, \
                 be.use(self._backend):
             # pad lanes replicate the last witness; their proofs are
             # discarded (bit-identity makes them redundant, not wrong)
             step_proofs = self.session.prove_steps(steps + [steps[-1]] * pad)
-        self.metrics.prove_us.observe(sp.seconds * 1e6)
+        self.metrics.observe_prove(circuit.name, sp.seconds * 1e6)
         self.metrics.inc("batches")
         self.metrics.inc("lanes", len(steps))
         self.metrics.inc("pad_lanes", pad)

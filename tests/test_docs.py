@@ -135,7 +135,8 @@ def test_serving_doc_matches_live_surfaces():
         assert f"`{phase}`" in text, \
             f"docs/serving.md metrics table is missing phase {phase}"
     for key in ("counters", "phase_us", "queue_wait_us", "prove_us",
-                "batch_occupancy", "keygen_cache", "depths"):
+                "prove_us_by_circuit", "batch_occupancy", "keygen_cache",
+                "depths"):
         assert f"`{key}`" in text, \
             f"docs/serving.md metrics table is missing key {key}"
     # architecture.md links the serving section

@@ -11,6 +11,7 @@ import dataclasses
 import textwrap
 import threading
 import time
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -194,16 +195,54 @@ def test_service_metrics_schema(db, owner, tiny_cfg):
         stats = svc.stats()
     # the documented schema (docs/serving.md) — exact top-level keys
     assert set(stats) == {"counters", "phase_us", "witness_us",
-                          "queue_wait_us", "prove_us", "batch_occupancy",
-                          "keygen_cache", "depths"}
+                          "queue_wait_us", "prove_us", "prove_us_by_circuit",
+                          "batch_occupancy", "keygen_cache", "depths"}
     assert set(stats["counters"]) == {"submitted", "completed", "failed",
                                       "batches", "lanes", "pad_lanes"}
     assert {"fri", "total"} <= set(stats["phase_us"])
+    assert set(stats["prove_us_by_circuit"]) == {"expand_el"}
     for stat in (stats["phase_us"]["total"], stats["witness_us"],
-                 stats["queue_wait_us"], stats["batch_occupancy"]):
+                 stats["queue_wait_us"], stats["batch_occupancy"],
+                 stats["prove_us_by_circuit"]["expand_el"]):
         assert set(stat) == {"count", "mean", "p50", "p95", "max"}
     assert set(stats["keygen_cache"]) == {"hits", "misses", "waits",
                                           "entries"}
+
+
+def test_service_prove_times_by_circuit_ic9(db, owner, tiny_cfg):
+    """One IC9 through the service: its set expansions, BiRC hops and
+    OrderBy are timed apart (``prove_us_by_circuit``), each batch span
+    names its circuit and rows, and with recording off nothing is
+    recorded."""
+    from repro import obs
+    from repro.core import ir
+    k = db.tables["person_knows_person"]
+    ids, deg = np.unique(np.concatenate([k.src, k.dst]), return_counts=True)
+    person = int(ids[np.argmax(deg)])
+    steps = ir.execute(db, ir.build_plan("IC9"), dict(person=person)).steps
+    want = Counter(st.op.circuit.name for st in steps)
+    assert want == {"expand_set_birc": 2, "expand_set": 2, "orderby": 1}
+    session = ZKGraphSession(db, tiny_cfg, commitments=owner.commitments)
+    with obs.recording() as rec:
+        with ProofService(session, max_batch=2, flush_interval=0.05) as svc:
+            svc.submit("IC9", dict(person=person)).result(timeout=600)
+    by = svc.stats()["prove_us_by_circuit"]
+    assert {c: h["count"] for c, h in by.items()} == want
+    assert all(h["mean"] > 0 for h in by.values())
+    batches = [r for r in rec.spans() if r.name == "zkg.prove_batch"]
+    assert sorted((b.attrs["circuit"], b.attrs["rows"]) for b in batches) \
+        == sorted((st.op.circuit.name, st.op.circuit.n_rows) for st in steps)
+    for b in batches:
+        phases = [r for r in rec.spans() if r.parent == b.id
+                  and r.name.startswith("zkg.prove.")]
+        assert phases and all(p.attrs["circuit"] == b.attrs["circuit"]
+                              for p in phases)
+    recorded = len(rec.spans())
+    with ProofService(session, max_batch=2, flush_interval=0.05) as svc:
+        svc.submit("IC9", dict(person=person)).result(timeout=600)
+    assert len(rec.spans()) == recorded
+    assert sum(h["count"] for h in
+               svc.stats()["prove_us_by_circuit"].values()) == len(steps)
 
 
 # ---------------------------------------------------------------------------
@@ -271,6 +310,31 @@ def test_histogram_percentiles():
     snap = h.snapshot()
     assert snap["count"] == 100 and snap["max"] == 100.0
     assert 45 <= snap["p50"] <= 55 and 90 <= snap["p95"] <= 100
+
+
+def test_prove_times_by_circuit_lose_no_observation_across_threads():
+    """Threads racing to make the first entry of a circuit all land in
+    one histogram per circuit, and the pooled one sees every batch."""
+    import sys
+    from repro.serve.metrics import ServiceMetrics
+    m, names, per = ServiceMetrics(), ("a", "b", "c"), 200
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(
+            target=lambda i=i: [m.observe_prove(names[(i + j) % 3], 1.0)
+                                for j in range(per)]) for i in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(was)
+    snap = m.snapshot()
+    assert sum(h["count"] for h in snap["prove_us_by_circuit"].values()) \
+        == snap["prove_us"]["count"] == 16 * per
+    assert set(snap["prove_us_by_circuit"]) == set(names)
 
 
 # ---------------------------------------------------------------------------
